@@ -19,7 +19,7 @@ Part 3 (``run_levels``) measures the exponent-prescan level pruning
 2 live levels, and the pruned table is bit-identical to the full one.
 
 ``cross_check`` is the CI gate: every path (radix partitions, level-pruned
-variants, the Pallas kernel in interpret mode, row permutations) must
+variants, the Pallas kernel, row permutations) must
 reproduce the seed scatter table bit for bit; any mismatch fails the
 process, so the benchmark lane doubles as a bitwise acceptance sweep.
 Results land in BENCH_groupby.json at the repo root.  ``--autotune`` first
@@ -359,7 +359,8 @@ def run_levels(quick: bool = True):
 def cross_check():
     """Every execution path must reproduce the seed scatter table bit for
     bit: radix partitions (several fan-outs), level-pruned variants, the
-    Pallas kernel (interpret mode), and row permutations.  Raises on any
+    Pallas kernel (compiled on a TPU, interpreted on the CPU backend), and
+    row permutations.  Raises on any
     mismatch, which fails the benchmark lane."""
     from repro.kernels.segment_rsum.ops import segment_agg_kernel
 
@@ -393,9 +394,8 @@ def cross_check():
         check(f"pruned {method} {window}",
               segment_table(vals, ids, g, spec, method=method, e1=e1,
                             levels=window, chunk_skip=True))
-    check("pallas interpret",
-          segment_agg_kernel(vals, ids, g, spec, e1=e1, interpret=True,
-                             levels=window))
+    check("pallas",
+          segment_agg_kernel(vals, ids, g, spec, e1=e1, levels=window))
     perm = rng.permutation(n)
     check("permuted rows",
           segment_table(vals[perm], ids[perm], g, spec, method="radix",

@@ -6,7 +6,9 @@ incremental aggregation).  Parts:
 * **TTFR** — first delta in -> first finalized result out, in-process
   (cold / warm / restored-from-snapshot) and in *fresh subprocesses* with
   the two cold-start mitigations toggled: ``StreamStore.warmup`` and the
-  persistent XLA compilation cache (``REPRO_COMPILATION_CACHE``);
+  persistent XLA compilation cache (:mod:`repro.compile_cache`).  The
+  subprocesses run before this process touches a device, because a chip
+  belongs to one process at a time;
 * **sustained** — concurrent writers through the asyncio NDJSON service,
   three configurations side by side in one run on one machine:
   **serialized** (the PR-5 shape: eager ``partial_agg`` under one global
@@ -37,6 +39,7 @@ import asyncio
 import json
 import os
 import resource
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -45,6 +48,7 @@ import time
 import numpy as np
 
 from benchmarks._util import timeit  # noqa: F401  (kept for parity/imports)
+from repro.compile_cache import CHECKOUT_CACHE_DIR, enable_compilation_cache
 from repro.obs import fingerprint as obs_fp
 from repro.ops import groupby_agg
 from repro.stream import (ReplicatedStore, ShardedStreamStore, StreamStore,
@@ -53,6 +57,10 @@ from repro.stream.service import LINE_LIMIT
 
 BENCH_JSON = os.path.join(os.path.dirname(__file__), "..",
                           "BENCH_stream.json")
+
+#: the cold-start probes' own compilation cache, emptied before they run so
+#: that the first probe populates it and the next ones hit
+PROBE_CACHE_DIR = CHECKOUT_CACHE_DIR.with_name(".jax_cache_ttfr_probe")
 
 G = 129
 AGGS = ("sum", "count", "mean", "var", "min", "max", ("sum", 1))
@@ -172,12 +180,14 @@ def _ttfr_probe(batch: int, warmup: bool) -> dict:
     return out
 
 
-def _spawn_ttfr_probe(batch: int, warmup: bool,
-                      cache_dir: str | None) -> dict:
+def _spawn_ttfr_probe(batch: int, warmup: bool, cache: bool) -> dict:
     env = dict(os.environ)
-    env.pop("REPRO_COMPILATION_CACHE", None)
-    if cache_dir is not None:
-        env["REPRO_COMPILATION_CACHE"] = cache_dir
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    if cache:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(PROBE_CACHE_DIR)
+    else:
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     argv = [sys.executable, os.path.abspath(__file__),
             "--ttfr-probe", str(batch)] + (["--warmup"] if warmup else [])
     proc = subprocess.run(argv, env=env, capture_output=True, text=True,
@@ -187,8 +197,34 @@ def _spawn_ttfr_probe(batch: int, warmup: bool,
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def run_ttfr(quick: bool = True) -> dict:
-    batch = 2048 if quick else 16384
+def _ttfr_batch(quick: bool) -> int:
+    return 2048 if quick else 16384
+
+
+def run_fresh_probes(quick: bool = True) -> dict:
+    """Fresh-process TTFR probes: the cold-start mitigations, measured where
+    cold actually happens.  Each child needs the device to itself, so this
+    runs before the calling process touches one.  The compilation-cache
+    probe runs twice in the same emptied cache dir — the first populates,
+    the second is the steady state an operator sees."""
+    batch = _ttfr_batch(quick)
+    probes = {}
+    probes["fresh"] = _spawn_ttfr_probe(batch, warmup=False, cache=False)
+    probes["fresh_warmup"] = _spawn_ttfr_probe(batch, warmup=True,
+                                               cache=False)
+    shutil.rmtree(PROBE_CACHE_DIR, ignore_errors=True)
+    _spawn_ttfr_probe(batch, warmup=True, cache=True)          # populate
+    probes["fresh_warmup_cache"] = _spawn_ttfr_probe(batch, warmup=True,
+                                                     cache=True)
+    probes["fresh_cache"] = _spawn_ttfr_probe(batch, warmup=False,
+                                              cache=True)
+    return probes
+
+
+def run_ttfr(probes: dict, quick: bool = True) -> dict:
+    """In-process TTFR (cold / warm / persistent) beside the fresh-process
+    ``probes`` that :func:`run_fresh_probes` took earlier."""
+    batch = _ttfr_batch(quick)
     v, k = _dataset(4 * batch, seed=3)
     out = {"batch_rows": batch}
     # cold: the first streamed batch this process ever aggregates at this
@@ -205,21 +241,6 @@ def run_ttfr(quick: bool = True) -> dict:
     print(f"\n== TTFR (batch={batch} rows) ==")
     for m in ("cold", "warm", "persistent"):
         print(f"  {m:10} {out[f'{m}_ttfr_s'] * 1e3:9.1f} ms")
-
-    # fresh-process probes: the cold-start mitigations, measured where cold
-    # actually happens.  The compilation-cache probe runs twice in the same
-    # cache dir — the first populates, the second is the steady state an
-    # operator sees.
-    probes = {}
-    probes["fresh"] = _spawn_ttfr_probe(batch, warmup=False, cache_dir=None)
-    probes["fresh_warmup"] = _spawn_ttfr_probe(batch, warmup=True,
-                                               cache_dir=None)
-    with tempfile.TemporaryDirectory() as cache:
-        _spawn_ttfr_probe(batch, warmup=True, cache_dir=cache)  # populate
-        probes["fresh_warmup_cache"] = _spawn_ttfr_probe(
-            batch, warmup=True, cache_dir=cache)
-        probes["fresh_cache"] = _spawn_ttfr_probe(batch, warmup=False,
-                                                  cache_dir=cache)
     out["fresh_process"] = probes
     print(f"  -- fresh subprocesses (cold-start mitigations) --")
     for name, p in probes.items():
@@ -449,8 +470,12 @@ def run_durability(quick: bool = True) -> dict:
 
 
 def emit_bench_json(quick: bool = True):
-    check = cross_check()                  # the gate: fail before timing
-    ttfr = run_ttfr(quick=quick)
+    # the probes' children need the device to themselves: spawn them while
+    # this process is still off it; their numbers are recorded only once
+    # the gate below has passed
+    probes = run_fresh_probes(quick=quick)
+    check = cross_check()                  # the gate: fail before recording
+    ttfr = run_ttfr(probes, quick=quick)
     sustained = run_sustained(quick=quick)
     durability = run_durability(quick=quick)
     payload = {"cross_check": check, "G": G,
@@ -465,6 +490,7 @@ def emit_bench_json(quick: bool = True):
 
 if __name__ == "__main__":
     if "--ttfr-probe" in sys.argv:
+        enable_compilation_cache()
         i = sys.argv.index("--ttfr-probe")
         probe = _ttfr_probe(int(sys.argv[i + 1]),
                             warmup="--warmup" in sys.argv)

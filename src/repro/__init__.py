@@ -1,18 +1,9 @@
 """repro: bit-reproducible floating-point aggregation for JAX training and
 inference at multi-pod scale (Mueller et al., ICDE'18, adapted to TPU)."""
-import os as _os
-
 from repro.core import (  # noqa: F401
     ReproSpec, ReproAcc, from_values, finalize, merge, segment_rsum,
     repro_psum,
 )
 from repro.ops import groupby_agg, plan_groupby, sharded_groupby_agg  # noqa: F401,E501
-
-# opt-in persistent XLA compilation cache (REPRO_COMPILATION_CACHE=<dir>):
-# cuts cold-start TTFR to roughly warm TTFR; cannot affect result bits
-# (see repro.compat.enable_compilation_cache)
-if _os.environ.get("REPRO_COMPILATION_CACHE"):
-    from repro.compat import enable_compilation_cache as _ecc
-    _ecc()
 
 __version__ = "1.0.0"

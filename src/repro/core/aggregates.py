@@ -86,7 +86,10 @@ def default_chunk(method: str, spec: ReproSpec) -> int:
     if method == "rsum":
         from repro.kernels.rsum.ops import max_block_rows
         return max_block_rows(spec)
-    if method in ("onehot", "pallas"):
+    if method == "pallas":
+        from repro.kernels.segment_rsum.ops import max_step_rows
+        return max_step_rows(spec)
+    if method == "onehot":
         return onehot_block_bound(spec)
     return min(scatter_chunk_bound(spec), 4096)
 
@@ -333,7 +336,9 @@ def onehot_table(values, segment_ids, num_segments, spec: ReproSpec, e1,
             A = eft.extractor(es[l], spec.dtype)             # (*F,)
             q, r = eft.eft_fixed(A, r)
             # exact: per-group |sum q| <= block * 2^(W-1) ulp <= 2^(m+1) ulp
-            s = jnp.einsum("n...,ng->g...", q, onehot)       # (nseg, *F)
+            # HIGHEST: the MXU's default f32 precision rounds q to bf16
+            s = jnp.einsum("n...,ng->g...", q, onehot,
+                           precision=lax.Precision.HIGHEST)  # (nseg, *F)
             parts.append((s * inv_ulp[l]).astype(idt))
         part = jnp.stack(parts, axis=-1)                     # (nseg, *F, nlev)
         k_tab, c_tab = acc_mod.renorm(k_tab + part, c_tab, spec)

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
 from repro.core import accumulator as acc_mod
 from repro.core.accumulator import ReproAcc
 from repro.core.types import ReproSpec
@@ -46,7 +45,7 @@ def max_axis_size(spec: ReproSpec) -> int:
 
 
 def _check_axis(axis_name, spec):
-    size = axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     if size > max_axis_size(spec):
         raise ValueError(
             f"axis {axis_name!r} of size {size} exceeds the exact-psum bound "
@@ -144,7 +143,7 @@ def repro_psum_packed(acc: ReproAcc, spec: ReproSpec, axis_names) -> ReproAcc:
         axis_names = (axis_names,)
     total = 1
     for ax in axis_names:
-        total *= axis_size(ax)
+        total *= lax.axis_size(ax)
     if spec.m > 30 or acc.k.ndim < 2 or acc.k.shape[0] % total != 0:
         return repro_psum(acc, spec, axis_names)   # packed layout N/A
     e1 = acc.e1

@@ -23,17 +23,16 @@ from repro.core import eft
 from repro.core import prescan
 from repro.core.accumulator import ReproAcc
 from repro.core.types import ReproSpec
+from repro.kernels.mode import resolve_interpret
 from repro.kernels.rsum.kernel import LANES, SUBLANES, rsum_pallas_call
 
 __all__ = ["rsum", "rsum_acc", "rsum_table", "max_block_rows"]
 
-# VMEM share budgeted for the input block + integer scratch (of ~16 MiB/core;
-# the rest is headroom for Pallas pipelining buffers)
+# Bytes of the TPU's scoped VMEM (16 MiB by default on v5e) that the
+# kernel's pipelined buffers may take: Pallas double-buffers every block, so
+# the input block counts twice, beside the ladders, outputs and scratch.  The
+# rest is headroom for the compiler's own scratch.
 VMEM_BUDGET_BYTES = 1 << 23
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def max_block_rows(spec: ReproSpec, ncols: int = 1,
@@ -48,11 +47,13 @@ def max_block_rows(spec: ReproSpec, ncols: int = 1,
       ``2^(m-2) + block_rows * 2^(W-1)``; ``block_rows <= 2^(30 - (W-1))``
       keeps that under ``2^21 + 2^30 < 2^31``.  This holds per level, for
       any live-level count.
-    * **VMEM** — the ``(ncols, block_rows, 128)`` f32 input block plus the
-      two ``(nlev, ncols, 128)`` int32 scratch accumulators must fit the
-      budget; the *pruned-window* level count ``nlev`` sizes the scratch, so
-      a wide ladder shrinks the block (this is what actually binds for W=12,
-      whose overflow bound alone would allow an absurd 2^19-row block).
+    * **VMEM** — Pallas double-buffers the ``(ncols, block_rows, 128)`` f32
+      input block, and the ``(nlev, ncols, 8, 128)`` tiles (two ladders, two
+      outputs, all double-buffered, plus two scratch accumulators) take ten
+      tiles per live level and column; all of it must fit the budget.  The
+      *pruned-window* level count ``nlev`` sizes the tiles, so a wide ladder
+      shrinks the block (this is what actually binds for W=12, whose
+      overflow bound alone would allow an absurd 2^19-row block).
 
     The result is a multiple of ``SUBLANES`` (f32 sublane tile) and at least
     ``SUBLANES``, so the zero-padded tail block consists of whole lane tiles
@@ -62,9 +63,9 @@ def max_block_rows(spec: ReproSpec, ncols: int = 1,
     overflow = 1 << (30 - (spec.W - 1))
     nlev = prescan.window_length(levels, spec)
     ncols = max(int(ncols), 1)
-    scratch = 2 * nlev * ncols * LANES * 4
-    free = max(VMEM_BUDGET_BYTES - scratch, 0)
-    rows = min(overflow, free // (ncols * LANES * 4))
+    tiles = 10 * nlev * ncols * SUBLANES * LANES * 4
+    free = max(VMEM_BUDGET_BYTES - tiles, 0)
+    rows = min(overflow, free // (2 * ncols * LANES * 4))
     return max((rows // SUBLANES) * SUBLANES, SUBLANES)
 
 
@@ -85,9 +86,12 @@ def rsum_table(values, segment_ids=None, num_segments: int = 1,
     dispatch-signature compatibility: with one group every row belongs to
     it.  ``levels`` is a prescan-proved live window; the returned table is
     full-L with exact zeros on pruned levels.
+
+    The kernel is compiled by Mosaic on the TPU backend and interpreted on
+    the CPU backend or where ``interpret=True`` asks for it; it is never
+    interpreted on a TPU (:func:`repro.kernels.mode.resolve_interpret`).
     """
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     if spec.m > 30:
         raise ValueError("the TPU kernel supports float32 accumulators")
     if num_segments != 1:

@@ -33,6 +33,19 @@ passes through unchanged); the skipped levels are re-embedded as exact
 zeros outside, keeping the full-L table bit-identical to an unpruned run
 while the per-block FLOPs, VMEM scratch and output DMA all shrink by
 ``L / (hi - lo)``.
+
+Layout (what the TPU compiler accepts, DESIGN.md §3.2): rows run along the
+128 lanes.  Each grid step streams ``step`` rows and walks them one
+128-lane sub-block at a time; the ids arrive as a ``(1, 128)`` lane vector
+and the one-hot is built transposed, ``(group_tile, 128)``, by comparing
+them against a sublane iota, so no id is ever moved from a lane into a
+sublane.  The extractor ladders and their inverse ulps arrive broadcast to
+lane-dense ``(L, ncols, 128)`` blocks.  The contraction is
+``(ncols, 128) x (group_tile, 128)^T`` at ``precision=HIGHEST``: the MXU's
+default f32 precision rounds operands to bf16, which would drop the low
+bits of the extracted integers.  Scaling ``q`` by ``1/ulp`` before the
+matmul is exact (a power of two), so the MXU sums small integers, and those
+sums are exact while a sub-block holds at most ``2^(m-W+2)`` real rows.
 """
 from __future__ import annotations
 
@@ -40,8 +53,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128      # rows per one-hot matmul: one lane tile
 
 
 def exact_block_bound(m: int, W: int) -> int:
@@ -50,8 +66,8 @@ def exact_block_bound(m: int, W: int) -> int:
 
 
 def _segment_kernel(ids_ref, x_ref, a_ref, iu_ref, k_out, c_out,
-                    k_acc, c_acc, *, L: int, m: int, block_n: int,
-                    ncols: int, group_tile: int):
+                    k_acc, c_acc, *, L: int, m: int, step: int,
+                    group_tile: int):
     ni = pl.program_id(1)
     nblk = pl.num_programs(1)
     gi = pl.program_id(0)
@@ -61,22 +77,30 @@ def _segment_kernel(ids_ref, x_ref, a_ref, iu_ref, k_out, c_out,
         k_acc[...] = jnp.zeros_like(k_acc)
         c_acc[...] = jnp.zeros_like(c_acc)
 
-    ids = ids_ref[...].reshape(block_n, 1)                   # int32
-    base = gi * group_tile
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_n, group_tile), 1) + base
-    onehot = (ids == col).astype(jnp.float32)                # (bn, gt)
+    groups = (jax.lax.broadcasted_iota(jnp.int32, (group_tile, LANES), 0)
+              + gi * group_tile)
 
-    r = x_ref[...].reshape(ncols, block_n)                   # f32
-    for l in range(L):
-        A = a_ref[l, :].reshape(ncols, 1)                    # per-column
-        q = (r + A) - A                                      # EFT, fixed A
-        r = r - q
-        # exact: per-group |sum q| <= block_n * 2^(W-1) ulp <= 2^(m+1) ulp
-        part = jax.lax.dot_general(
-            q, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (ncols, gt)
-        k_acc[l, :, :] += (part * iu_ref[l, :].reshape(ncols, 1)
-                           ).astype(jnp.int32)
+    def sub_block(s, carry):
+        col = pl.multiple_of(s * LANES, LANES)
+        ids = ids_ref[0, :, pl.ds(col, LANES)]               # (1, 128) int32
+        onehot_t = (groups == ids).astype(jnp.float32)       # (gt, 128)
+        r = x_ref[0, :, pl.ds(col, LANES)]                   # (ncols, 128)
+        for l in range(L):
+            A = a_ref[l]                                     # per-column
+            q = (r + A) - A                                  # EFT, fixed A
+            r = r - q
+            # exact: |q / ulp| < 2^(W-1) and a sub-block holds at most
+            # 2^(m-W+2) real rows, so every partial sum is below 2^(m+1)
+            part = jax.lax.dot_general(
+                q * iu_ref[l], onehot_t, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)          # (ncols, gt)
+            k_acc[l] += part.astype(jnp.int32)
+        return carry
+
+    # int32 bounds: under jax_enable_x64 Python ints would make the index
+    # int64, which Mosaic does not take
+    jax.lax.fori_loop(np.int32(0), np.int32(step // LANES), sub_block, None)
 
     kk = k_acc[...]
     d = kk >> (m - 2)                                        # carry prop.
@@ -89,29 +113,32 @@ def _segment_kernel(ids_ref, x_ref, a_ref, iu_ref, k_out, c_out,
         c_out[...] = c_acc[...]
 
 
-def segment_rsum_pallas_call(ids2d, x3d, A, inv_ulp, *, L: int, m: int,
-                             block_n: int, group_tile: int, num_group_tiles:
-                             int, interpret: bool):
-    """ids2d: (nblk, block_n); x3d: (nblk, ncols, block_n);
-    A/inv_ulp: (L, ncols) f32.  Returns (k, C): (L, ncols, G_padded) int32
-    with G_padded = tiles * group_tile."""
-    nblk, ncols = x3d.shape[0], x3d.shape[1]
-    kernel = functools.partial(_segment_kernel, L=L, m=m, block_n=block_n,
-                               ncols=ncols, group_tile=group_tile)
+def segment_rsum_pallas_call(ids3d, x3d, A, inv_ulp, *, L: int, m: int,
+                             group_tile: int, num_group_tiles: int,
+                             interpret: bool):
+    """ids3d: (nblk, 1, step) int32; x3d: (nblk, ncols, step) f32, with
+    ``step`` a multiple of 128; A/inv_ulp: (L, ncols) f32.  Returns (k, C):
+    (L, ncols, G_padded) int32 with G_padded = tiles * group_tile, and
+    ``group_tile`` a multiple of 128."""
+    nblk, ncols, step = x3d.shape
+    assert step % LANES == 0 and group_tile % LANES == 0
+    kernel = functools.partial(_segment_kernel, L=L, m=m, step=step,
+                               group_tile=group_tile)
+    ladder = (L, ncols, LANES)
+    A = jnp.broadcast_to(A[:, :, None], ladder)
+    inv_ulp = jnp.broadcast_to(inv_ulp[:, :, None], ladder)
     g_total = num_group_tiles * group_tile
+    table = pl.BlockSpec((L, ncols, group_tile), lambda gi, ni: (0, 0, gi))
     return pl.pallas_call(
         kernel,
         grid=(num_group_tiles, nblk),
         in_specs=[
-            pl.BlockSpec((1, block_n), lambda gi, ni: (ni, 0)),
-            pl.BlockSpec((1, ncols, block_n), lambda gi, ni: (ni, 0, 0)),
-            pl.BlockSpec((L, ncols), lambda gi, ni: (0, 0)),
-            pl.BlockSpec((L, ncols), lambda gi, ni: (0, 0)),
+            pl.BlockSpec((1, 1, step), lambda gi, ni: (ni, 0, 0)),
+            pl.BlockSpec((1, ncols, step), lambda gi, ni: (ni, 0, 0)),
+            pl.BlockSpec(ladder, lambda gi, ni: (0, 0, 0)),
+            pl.BlockSpec(ladder, lambda gi, ni: (0, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((L, ncols, group_tile), lambda gi, ni: (0, 0, gi)),
-            pl.BlockSpec((L, ncols, group_tile), lambda gi, ni: (0, 0, gi)),
-        ],
+        out_specs=[table, table],
         out_shape=[
             jax.ShapeDtypeStruct((L, ncols, g_total), jnp.int32),
             jax.ShapeDtypeStruct((L, ncols, g_total), jnp.int32),
@@ -121,4 +148,4 @@ def segment_rsum_pallas_call(ids2d, x3d, A, inv_ulp, *, L: int, m: int,
             pltpu.VMEM((L, ncols, group_tile), jnp.int32),
         ],
         interpret=interpret,
-    )(ids2d, x3d, A, inv_ulp)
+    )(ids3d, x3d, A, inv_ulp)

@@ -23,7 +23,6 @@ import jax            # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat                                  # noqa: E402
 from repro import configs as registry                     # noqa: E402
 from repro.launch import specs as specs_mod               # noqa: E402
 from repro.launch import shardings as sh                  # noqa: E402
@@ -99,7 +98,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
             "heads" if cfg.n_kv_heads % msize == 0 else "replicate"))
     t0 = time.time()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tc = TrainConfig(grad_mode=grad_mode, remat=remat)
             local_step, batch_specs_fn = make_train_step(cfg, tc, mesh, shape)
@@ -112,7 +111,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                 specs_mod.opt_pspecs(cfg, mesh,
                                      zero=grad_mode == "repro_zero2"),
                 manual)
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 local_step, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), p_specs),
                           o_pspecs, batch_specs_fn(b_specs)),

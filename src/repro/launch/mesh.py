@@ -11,19 +11,27 @@ DP runs over ("pod", "data"); TP/EP/sequence-CP over "model".
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the model code places its tensor-parallel layouts with
+    # sharding constraints that the partitioner propagates (DESIGN.md §5);
+    # jax's default Explicit axes would make every such layout a type
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
     """Small meshes for tests/examples on whatever devices exist."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

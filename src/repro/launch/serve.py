@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro import configs as registry
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
@@ -100,7 +99,7 @@ def main(argv=None):
     if cfg.embed_frontend == "stub":
         raise SystemExit("serve CLI demo supports token-frontend archs")
     mesh = make_host_mesh(args.data, args.model)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(jax.random.PRNGKey(args.seed), cfg)
         rng = np.random.default_rng(args.seed)
         prompts = jnp.asarray(

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro import configs as registry
 from repro.checkpoint import ckpt as ckpt_mod
 from repro.data.pipeline import DataConfig, synth_batch
@@ -104,14 +103,14 @@ def train_loop(model_cfg: ModelConfig, shape: ShapeConfig,
 
     p_pspecs = jax.tree.map(lambda _: P(), p_shardings)
     o_pspecs = sh.tree_manual_only(o_specs_tree, manual)
-    step_fn = jax.jit(compat.shard_map(
+    step_fn = jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(p_pspecs, o_pspecs, batch_specs_fn(b0)),
         out_specs=(p_pspecs, o_pspecs, P()),
         axis_names=manual, check_vma=False), donate_argnums=(0, 1))
 
     def fresh() -> RunState:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(
                 lambda: lm.init_params(jax.random.PRNGKey(seed), model_cfg),
                 out_shardings=p_shardings)()
@@ -157,7 +156,7 @@ def train_loop(model_cfg: ModelConfig, shape: ShapeConfig,
             with obs_trace.span("train.build_batch", step=step):
                 batch = build_batch(dcfg, model_cfg, step, n_quanta,
                                     train_cfg.mb_size)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 params, opt, metrics = step_fn(state.params, state.opt,
                                                batch)
             loss_arr = np.asarray(metrics["loss"])

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import accumulator as acc_mod
 from repro.core import collectives
 from repro.core.accumulator import ReproAcc
@@ -196,8 +195,10 @@ def make_train_step(model_cfg: ModelConfig, train_cfg: TrainConfig,
                     return collectives.repro_psum(a, spec, dpx)
                 return collectives.repro_psum_scatter(a, spec, dpx,
                                                       dim=zdim)
-            f = compat.shard_map(
-                inner, mesh=mesh,
+            # (the mesh comes from the context: inside the outer shard_map
+            # it is the one whose data axes are already manual)
+            f = jax.shard_map(
+                inner,
                 in_specs=(ReproAcc(k=mspec, C=mspec, e1=P()),),
                 out_specs=ReproAcc(k=mspec, C=mspec, e1=P()),
                 axis_names={"model"}, check_vma=False)
@@ -268,7 +269,7 @@ def make_train_step(model_cfg: ModelConfig, train_cfg: TrainConfig,
     def _dp_index():
         idx = lax.axis_index(dpx[0])
         for ax in dpx[1:]:
-            idx = idx * compat.axis_size(ax) + lax.axis_index(ax)
+            idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
         return idx
 
     def _shard_global_norm(g_shards, zero_axes):
@@ -312,7 +313,7 @@ def wrap_train_step(local_step, batch_specs_fn, mesh, params_tree,
     o_specs = opt_specs if opt_specs is not None else jax.tree.map(
         lambda _: P(), opt_tree)
     b_specs = batch_specs_fn(batch_tree)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(p_specs, o_specs, b_specs),
         out_specs=(p_specs, o_specs, P()),

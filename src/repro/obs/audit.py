@@ -23,9 +23,14 @@ fingerprints as the only channel of comparison:
 * **Train family** — a short training run fingerprinted end-to-end
   (chained per-step loss/grad-norm digests + final params/opt), repeated
   in fresh processes, across data-parallel mesh widths
-  (``--xla_force_host_platform_device_count``), and across the
+  (``--xla_force_host_platform_device_count``, so every worker of this
+  family runs on the CPU backend, ``JAX_PLATFORMS=cpu``), and across the
   reproducible embedding-gradient GROUPBY chunk (``TrainConfig.embed_chunk``
   — the chunk knob that *is* bitwise-invariant, unlike ``xent_chunk``).
+
+Workers that may use an accelerator run one at a time, since a chip
+belongs to one process: only where ``JAX_PLATFORMS=cpu`` do they run
+concurrently.  The parent never touches a device itself.
 
 The parent diffs the fingerprint files with
 :func:`repro.obs.fingerprint.diff_fingerprints` and exits non-zero on any
@@ -212,7 +217,11 @@ def _worker_train(args) -> int:
 # ---------------------------------------------------------------------------
 # parent: spawn, collect, diff
 
-def _worker_env(out: str, tag: str, dp: int = 1) -> dict:
+def _worker_env(out: str, tag: str, dp: int | None = None) -> dict:
+    """The environment of one worker.  ``dp`` (a forced host device count)
+    pins the worker to the CPU backend: forced host devices exist only
+    there, and every worker of a family that needs them must share one
+    backend for its fingerprints to compare."""
     env = dict(os.environ)
     env["REPRO_TRACE"] = os.path.join(out, f"trace_{tag}.jsonl")
     env["REPRO_METRICS"] = os.path.join(out, f"metrics_{tag}.json")
@@ -220,33 +229,46 @@ def _worker_env(out: str, tag: str, dp: int = 1) -> dict:
     # may differ with calibration, results must not
     env["REPRO_CALIBRATION_CACHE"] = os.path.join(out, "calibration.json")
     env["REPRO_AUTOTUNE"] = "0"
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if not f.startswith("--xla_force_host_platform_device_count")]
-    flags.append(f"--xla_force_host_platform_device_count={dp}")
-    env["XLA_FLAGS"] = " ".join(flags)
+    if dp is not None:
+        env["JAX_PLATFORMS"] = "cpu"
+        flags = [f for f in env.get("XLA_FLAGS", "").split()
+                 if not f.startswith("--xla_force_host_platform_device_count")]
+        flags.append(f"--xla_force_host_platform_device_count={dp}")
+        env["XLA_FLAGS"] = " ".join(flags)
     return env
 
 
-def _spawn(worker: str, out: str, tag: str, extra_args: list,
-           dp: int = 1) -> "subprocess.Popen":
+def _cpu_only(env: dict) -> bool:
+    """Whether a worker with this environment is held to the CPU backend
+    (decided from the environment alone: the parent stays off JAX)."""
+    return env.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def _job(worker: str, out: str, tag: str, extra_args: list,
+         dp: int | None = None) -> tuple:
+    """One worker run: (tag, argv, env)."""
     cmd = [sys.executable, "-m", "repro.obs.audit", "--worker", worker,
            "--out", out, "--tag", tag] + extra_args
-    return subprocess.Popen(cmd, env=_worker_env(out, f"{worker}_{tag}", dp),
-                            stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                            text=True)
+    return tag, cmd, _worker_env(out, f"{worker}_{tag}", dp)
 
 
 def _run_family(family: str, jobs: list, serial: bool) -> list:
-    """jobs: (tag, popen-factory).  Returns failed tags."""
+    """jobs: (tag, argv, env).  Returns failed tags.  CPU-only workers run
+    concurrently unless ``serial``; a worker that may use an accelerator
+    ends before the next one starts, because a chip belongs to one
+    process at a time."""
     failed = []
     procs = []
-    for tag, factory in jobs:
-        p = factory()
-        procs.append((tag, p))
-        if serial:
-            p.wait()
-    for tag, p in procs:
-        output = p.communicate()[0]
+    for tag, cmd, env in jobs:
+        p = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
+                             stderr=subprocess.STDOUT, text=True)
+        output = None
+        if serial or not _cpu_only(env):
+            output = p.communicate()[0]
+        procs.append((tag, p, output))
+    for tag, p, output in procs:
+        if output is None:
+            output = p.communicate()[0]
         if p.returncode != 0:
             print(f"[{family}] worker {tag} FAILED (exit {p.returncode}):")
             print(output[-4000:] if output else "  <no output>")
@@ -296,8 +318,7 @@ def _audit(args) -> int:
                 extra += ["--chunk", str(ov["chunk"])]
             if ov.get("permute"):
                 extra += ["--permute"]
-            jobs.append((tag, (lambda t=tag, e=extra:
-                               _spawn("groupby", args.out, t, e))))
+            jobs.append(_job("groupby", args.out, tag, extra))
         failed = _run_family("groupby", jobs, serial=args.serial)
         if failed:
             failures.append(f"groupby workers failed: {failed}")
@@ -317,8 +338,7 @@ def _audit(args) -> int:
                 extra += ["--permute-batches"]
             if ov.get("restart_after"):
                 extra += ["--restart-after", str(ov["restart_after"])]
-            jobs.append((tag, (lambda t=tag, e=extra:
-                               _spawn("stream", args.out, t, e))))
+            jobs.append(_job("stream", args.out, tag, extra))
         failed = _run_family("stream", jobs, serial=args.serial)
         if failed:
             failures.append(f"stream workers failed: {failed}")
@@ -335,8 +355,7 @@ def _audit(args) -> int:
         for tag, ov in TRAIN_VARIANTS:
             extra = ["--steps", str(TRAIN_STEPS), "--dp", str(ov["dp"]),
                      "--embed-chunk", str(ov["embed_chunk"])]
-            jobs.append((tag, (lambda t=tag, e=extra, d=ov["dp"]:
-                               _spawn("train", args.out, t, e, dp=d))))
+            jobs.append(_job("train", args.out, tag, extra, dp=ov["dp"]))
         # train workers each compile a model: run serially to bound memory
         failed = _run_family("train", jobs, serial=True)
         if failed:
@@ -374,7 +393,8 @@ def main(argv=None) -> int:
     ap.add_argument("--skip-groupby", action="store_true")
     ap.add_argument("--skip-stream", action="store_true")
     ap.add_argument("--serial", action="store_true",
-                    help="run GROUPBY workers one at a time")
+                    help="run CPU workers one at a time too (workers "
+                         "that may use an accelerator always do)")
     # worker mode (internal)
     ap.add_argument("--worker", choices=["groupby", "stream", "train"])
     ap.add_argument("--tag", default="base")

@@ -77,7 +77,10 @@ def _clamp_chunk(method: str, chunk: int, spec: ReproSpec) -> int:
     if method == "rsum":
         from repro.kernels.rsum.ops import max_block_rows
         return min(chunk, max_block_rows(spec))
-    if method in ("onehot", "pallas"):
+    if method == "pallas":
+        from repro.kernels.segment_rsum.ops import max_step_rows
+        return min(chunk, max_step_rows(spec))
+    if method == "onehot":
         return min(chunk, onehot_block_bound(spec))
     return min(chunk, scatter_chunk_bound(spec))
 
@@ -96,7 +99,12 @@ def pick_chunk(method: str, num_segments: int, ncols: int, spec: ReproSpec,
         # live-level scratch (see kernels.rsum.ops.max_block_rows)
         from repro.kernels.rsum.ops import max_block_rows
         return max_block_rows(spec, ncols, levels)
-    if method in ("onehot", "pallas"):
+    if method == "pallas":
+        # the segment kernel's rows per grid step (each step sums 128-row
+        # sub-blocks exactly; see kernels.segment_rsum.ops.max_step_rows)
+        from repro.kernels.segment_rsum.ops import max_step_rows
+        return max_step_rows(spec)
+    if method == "onehot":
         return onehot_block_bound(spec)
     bound = scatter_chunk_bound(spec)
     tb = table_bytes(num_segments, ncols, spec, levels)

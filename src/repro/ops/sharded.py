@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import accumulator as acc_mod
 from repro.core import aggregates, collectives
 from repro.core.accumulator import ReproAcc
@@ -105,10 +104,11 @@ def sharded_partial_agg(values, keys, num_segments: int, aggs=("sum",),
         maxs = lax.pmax(jax.ops.segment_max(m_s, id_s, nseg1), axis_name)
         return tab.k, tab.C, tab.e1, mins, maxs
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
-        out_specs=(P(), P(), P(), P(), P()), axis_names={axis_name})
+        out_specs=(P(), P(), P(), P(), P()), axis_names={axis_name},
+        check_vma=False)
     k, C, e1, mins, maxs = jax.jit(fn)(X, keys, M)
 
     # slice off the dump group: what remains is exactly the partial a

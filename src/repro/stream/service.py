@@ -53,6 +53,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.runtime.failures import exponential_backoff
@@ -427,6 +428,7 @@ def main(argv=None):
     ap.add_argument("--warmup", type=int, default=0, metavar="ROWS",
                     help="pre-trace the ingest path for this batch size")
     args = ap.parse_args(argv)
+    print(f"compilation cache: {enable_compilation_cache()}")
 
     async def run():
         resume = args.wal is not None and os.path.exists(args.wal)

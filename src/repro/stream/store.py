@@ -385,9 +385,10 @@ class StreamStore(_DurableMixin):
         Runs ``prepare`` on a synthetic full-magnitude-spread batch (so the
         prescan proves the widest level window), one coalescing-depth merge
         and one ``finalize`` — all into throwaways, so the store's state,
-        counters and fingerprints are untouched.  With
-        ``REPRO_COMPILATION_CACHE`` set (see :mod:`repro.compat`) the XLA
-        executables persist, and a *fresh process* skips compilation too.
+        counters and fingerprints are untouched.  Where the process has
+        turned on the persistent compilation cache (see
+        :mod:`repro.compile_cache`), the XLA executables persist, and a
+        *fresh process* skips compilation too.
         Batches whose prescan proves a narrower window still pay their own
         (cheaper) specialization on first sight.
         """

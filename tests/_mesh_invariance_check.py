@@ -20,7 +20,6 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro import compat  # noqa: E402
 from repro.core import accumulator as acc_mod  # noqa: E402
 from repro.core import collectives  # noqa: E402
 from repro.core.types import ReproSpec  # noqa: E402
@@ -46,7 +45,7 @@ def local_reduce(g):
 
 
 out = jax.jit(
-    compat.shard_map(local_reduce, mesh=mesh, in_specs=P("data", None),
+    jax.shard_map(local_reduce, mesh=mesh, in_specs=P("data", None),
                      out_specs=P(), check_vma=False),
 )(grads)
 

@@ -17,7 +17,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro import configs as registry  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.launch.train import train_loop  # noqa: E402
@@ -45,7 +44,7 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 # one explicit step, hash params (isolates metric-vs-param divergence)
 dcfg = DataConfig(seed=7, global_batch=8, seq_len=32, vocab=cfg.vocab)
 local_step, batch_specs_fn = make_train_step(cfg, tc, mesh, shape)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     params = lm.init_params(jax.random.PRNGKey(7), cfg)
     opt = adamw_mod2.init(params)
     b = build_batch(dcfg, cfg, 0, 8, 1)
@@ -53,7 +52,7 @@ with compat.set_mesh(mesh):
     o_pspecs = shd.tree_manual_only(specs_mod.opt_pspecs(cfg, mesh,
         zero=(grad_mode == "repro_zero2")), manual)
     p_pspecs = jax.tree.map(lambda _: P(), params)
-    fn = jax.jit(compat.shard_map(local_step, mesh=mesh,
+    fn = jax.jit(jax.shard_map(local_step, mesh=mesh,
         in_specs=(p_pspecs, o_pspecs, batch_specs_fn(b)),
         out_specs=(p_pspecs, o_pspecs, P()), axis_names=manual,
         check_vma=False))

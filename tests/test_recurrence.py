@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 from jax import lax
 
-from repro import compat
 from repro import configs as cfg_registry
 from repro.models import lm
 from repro.models.recurrence import chunked_time_scan
@@ -60,7 +59,7 @@ def test_attn_shard_modes_smoke(mode):
                                jnp.int32),
     }
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l_auto = float(lm.loss_fn(params, batch, cfg)[0])
         l_mode = float(lm.loss_fn(params, batch, cfg2)[0])
     assert np.float32(l_auto).tobytes() == np.float32(l_mode).tobytes()

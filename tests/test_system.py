@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro import configs as registry
 from repro.launch import serve
 from repro.launch.mesh import make_host_mesh
@@ -31,7 +30,7 @@ def test_training_reduces_loss():
 def test_generation_end_to_end():
     cfg = registry.get_config("smollm-135m").reduced()
     mesh = make_host_mesh(1, 1)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(jax.random.PRNGKey(0), cfg)
         rng = np.random.default_rng(0)
         prompts = jnp.asarray(rng.integers(0, cfg.vocab, (2, 8)), jnp.int32)

@@ -536,9 +536,13 @@ def test_service_deadline_answers_timeout_and_completes():
                                 "keys": k.tolist(), "client": "t",
                                 "seq": 0})
         assert out["ok"] is False and out["timeout"] is True
-        # the shielded operation completed in the background: the retry
-        # with the same tag is deduplicated, not double-counted
-        await asyncio.sleep(0.2)
+        # the shielded operation completes in the background (its first
+        # ingest compiles, which takes a varying time): once it has, the
+        # retry with the same tag is deduplicated, not double-counted
+        for _ in range(600):
+            if store.rows:
+                break
+            await asyncio.sleep(0.05)
         svc.request_timeout = None
         out2 = await svc.handle({"op": "ingest", "values": v.tolist(),
                                  "keys": k.tolist(), "client": "t",
