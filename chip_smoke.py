@@ -192,7 +192,7 @@ def emit(record: dict) -> None:
 
 def run_groupby(values, keys, groups: int, aggs, spec, method: str):
     """``groupby_agg`` with its table; returns a record of the run and the
-    outputs.  The plan is read back from the engine's own trace events."""
+    outputs.  The plan is read back from the engine's own trace records."""
     from repro.obs import fingerprint as obs_fp
     from repro.obs import trace as obs_trace
     from repro.ops import groupby_agg
@@ -207,7 +207,7 @@ def run_groupby(values, keys, groups: int, aggs, spec, method: str):
         obs_trace.disable()
     plan = [e["attrs"] for e in events if e["name"] == "plan.groupby"][-1]
     stats = [e["attrs"] for e in events
-             if e["name"] == "groupby.prescan_stats"][-1]
+             if e["name"] == "groupby.prescan"][-1]
     rec = {"method": method, "plan": plan["method"], "chunk": plan["chunk"],
            "levels": stats["levels"], "seconds": sec, "compile_s": comp,
            "table_fp": obs_fp.fingerprint_table(tab),

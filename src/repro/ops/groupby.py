@@ -28,7 +28,10 @@ are exact and order-independent as-is.
 """
 from __future__ import annotations
 
+import numpy as np
+
 from repro.core.types import ReproSpec
+from repro.obs import trace as obs_trace
 # Compilation/finalization helpers live in repro.ops.partial now; re-exported
 # here because sharded.py and external callers historically import them from
 # this module.
@@ -77,15 +80,22 @@ def groupby_agg(values, keys, num_segments: int, aggs=("sum",),
                     non-finite values, instead of silently leaving the
                     reproducibility contract.  Needs concrete inputs.
 
+    The call is one ``groupby.query`` span, the root of the query's
+    ``groupby.*`` spans.
+
     Returns an ordered dict mapping canonical names (see :func:`agg_name`)
     to finalized (G,) arrays; with ``return_table=True``, a
     ``(results, table)`` pair.  Every output is bit-identical across
     methods, row orderings, chunk sizes, level windows and shardings.
     """
-    state = partial_agg(values, keys, num_segments, aggs=aggs, spec=spec,
-                        method=method, chunk=chunk, levels=levels,
-                        check_finite=check_finite)
-    out = finalize(state)
+    with obs_trace.span("groupby.query", G=int(num_segments),
+                        method=method) as sp:
+        state = partial_agg(values, keys, num_segments, aggs=aggs, spec=spec,
+                            method=method, chunk=chunk, levels=levels,
+                            check_finite=check_finite)
+        if obs_trace.enabled():
+            sp.set(n=int(np.shape(values)[0]), ncols=state.sig.ncols)
+        out = finalize(state)
     if return_table:
         return out, state.table
     return out

@@ -328,32 +328,62 @@ def _resolve_levels(levels, X, e1, spec: ReproSpec):
     stats = prescan.chunk_stats(
         aggregates.pad_and_chunk(X, probe), spec)            # (nblk, ncols)
     lo_a, hi_a = prescan.level_window(stats, e1[None, :], spec)
-    lo, hi = int(jnp.min(lo_a)), int(jnp.max(hi_a))
+    lo = _host_sync("lo", int, jnp.min(lo_a))
+    hi = _host_sync("hi", int, jnp.max(hi_a))
     if lo >= hi:
         lo, hi = 0, 1                            # degenerate: all-zero input
     # heterogeneous when some chunk's own window starts above the union's
     # lo, i.e. that chunk can skip more top levels than the static window
-    chunk_skip = hi - lo > 1 and bool(
+    chunk_skip = hi - lo > 1 and _host_sync(
+        "chunk_skip", bool,
         jnp.max(jnp.min(lo_a.reshape(lo_a.shape[0], -1), axis=1)) > lo)
     return (lo, hi), chunk_skip
 
 
-def _emit_prescan_stats(n, ncols, spec: ReproSpec, lv, chunk_skip, plan):
-    """Record what the batch-adaptive prescan proved: L vs L_eff per run,
-    chunk count, and whether the per-chunk top-skip engaged (DESIGN.md §13.4).
-    No-op when observability is disabled."""
-    l_eff = prescan.window_length(lv, spec)
-    chunks = -(-int(n) // plan.chunk) if plan.chunk else 0
-    obs_trace.event("groupby.prescan_stats", n=int(n), ncols=int(ncols),
-                    L=spec.L, L_eff=l_eff,
-                    levels=list(lv) if lv is not None else None,
-                    chunk_skip=bool(chunk_skip), chunk=plan.chunk,
-                    chunks=chunks)
+def _host_sync(what: str, convert, x):
+    """``convert(x)`` of a dispatched device array: the host waits for the
+    device, timed as a ``groupby.host_sync`` span."""
+    with obs_trace.span("groupby.host_sync", what=what):
+        return convert(x)
+
+
+def _prescan(X, levels, spec: ReproSpec):
+    """Per-column ``required_e1`` and the resolved level window, timed as
+    the ``groupby.prescan`` span: (e1, levels, chunk_skip)."""
+    with obs_trace.span("groupby.prescan", n=int(X.shape[0]),
+                        ncols=X.shape[1]) as sp:
+        e1 = acc_mod.required_e1(X, spec, axis=0)            # per-column
+        lv, chunk_skip = _resolve_levels(levels, X, e1, spec)
+        sp.set(levels=list(lv) if lv is not None else None,
+               chunk_skip=bool(chunk_skip), L=spec.L,
+               L_eff=prescan.window_length(lv, spec))
+    return e1, lv, chunk_skip
+
+
+def _count_groupby(n, spec: ReproSpec, lv, plan):
+    """The engine counters of one batch: rows, calls per method and the
+    levels the prescan pruned (DESIGN.md §13.4).  No-op when metrics are
+    disabled."""
     obs_metrics.counter("repro_groupby_rows_total").inc(int(n))
     obs_metrics.counter("repro_groupby_calls_total",
                         method=plan.method).inc()
     obs_metrics.counter("repro_groupby_levels_pruned_total").inc(
-        spec.L - l_eff)
+        spec.L - prescan.window_length(lv, spec))
+
+
+def _columns(values, keys, cols, spec: ReproSpec, check_finite: bool):
+    """The eager front, timed as the ``groupby.columns`` span: the value
+    matrix, the int32 key column and the stacked accumulator columns,
+    checked for non-finite values where asked: (v, keys, X)."""
+    with obs_trace.span("groupby.columns"):
+        v = _as_matrix(values, spec)
+        keys = jnp.asarray(keys, jnp.int32).reshape(-1)
+        if v.shape[0] != keys.shape[0]:
+            raise ValueError("values and keys disagree on the row count")
+        X = _build_columns(v, cols, spec)
+        if check_finite:
+            _check_finite(v, X, cols)
+    return v, keys, X
 
 
 def partial_agg(values, keys, num_segments: int, aggs=("sum",),
@@ -373,26 +403,16 @@ def partial_agg(values, keys, num_segments: int, aggs=("sum",),
     """
     sig = AggSignature.build(aggs, num_segments, spec)
     spec = sig.spec
-    v = _as_matrix(values, spec)
-    keys = jnp.asarray(keys, jnp.int32).reshape(-1)
-    if v.shape[0] != keys.shape[0]:
-        raise ValueError("values and keys disagree on the row count")
-    names, cols, plans = sig.compiled
-    X = _build_columns(v, cols, spec)
+    v, keys, X = _columns(values, keys, sig.compiled[1], spec, check_finite)
     ncols = X.shape[1]
-    if check_finite:
-        _check_finite(v, X, cols)
 
     if ncols:
-        with obs_trace.span("groupby.prescan", n=int(X.shape[0]),
-                            ncols=ncols) as sp:
-            e1 = acc_mod.required_e1(X, spec, axis=0)        # per-column
-            lv, chunk_skip = _resolve_levels(levels, X, e1, spec)
-            sp.set(levels=list(lv) if lv is not None else None,
-                   chunk_skip=bool(chunk_skip))
+        e1, lv, chunk_skip = _prescan(X, levels, spec)
         plan = plan_groupby(int(X.shape[0]), num_segments, spec, ncols=ncols,
                             method=method, chunk=chunk, levels=lv)
-        _emit_prescan_stats(X.shape[0], ncols, spec, lv, chunk_skip, plan)
+        _count_groupby(X.shape[0], spec, lv, plan)
+        # times the host's enqueue of the strategy: the device's time for
+        # it is in the device trace
         with obs_trace.span("groupby.aggregate", method=plan.method,
                             chunk=plan.chunk, buckets=plan.buckets,
                             n=int(X.shape[0]), G=int(num_segments)):
@@ -545,17 +565,18 @@ def finalize(state: PartialState):
     A pure function of the canonical state, so two states that are
     bit-identical (one-shot vs any merge tree) finalize to bit-identical
     results — the argument that lets the streaming engine answer queries
-    mid-stream without losing the reproducibility contract.
+    mid-stream without losing the reproducibility contract.  All of it is
+    timed as the ``groupby.finalize`` span.
     """
     sig = state.sig
     spec = sig.spec
     names, cols, plans = sig.compiled
     with obs_trace.span("groupby.finalize"):
         sums = acc_mod.finalize(state.table, spec)           # (G, ncols)
-    mm = sig.minmax
-    mins = {j: state.minv[:, i] for i, j in enumerate(mm)}
-    maxs = {j: state.maxv[:, i] for i, j in enumerate(mm)}
-    return _finalize_plans(names, plans, sums, mins, maxs, spec)
+        mm = sig.minmax
+        mins = {j: state.minv[:, i] for i, j in enumerate(mm)}
+        maxs = {j: state.maxv[:, i] for i, j in enumerate(mm)}
+        return _finalize_plans(names, plans, sums, mins, maxs, spec)
 
 
 # ---------------------------------------------------------------------------
@@ -642,32 +663,22 @@ class PartialPipeline:
         pipeline's configuration, amortizing compilation across calls."""
         sig = self.sig
         spec = sig.spec
-        v = _as_matrix(values, spec)
-        keys = jnp.asarray(keys, jnp.int32).reshape(-1)
-        if v.shape[0] != keys.shape[0]:
-            raise ValueError("values and keys disagree on the row count")
-        names, cols, plans = sig.compiled
-        X = _build_columns(v, cols, spec)
+        v, keys, X = _columns(values, keys, sig.compiled[1], spec,
+                              self.check_finite)
         ncols = X.shape[1]
-        if self.check_finite:
-            _check_finite(v, X, cols)
         if not ncols:
             # min/max-only stores are rare and tiny: keep one code path
             return partial_agg(values, keys, sig.num_segments, aggs=sig.aggs,
                                spec=spec, method=self.method,
                                levels=self.levels,
                                check_finite=self.check_finite)
-        with obs_trace.span("groupby.prescan", n=int(X.shape[0]),
-                            ncols=ncols) as sp:
-            e1 = acc_mod.required_e1(X, spec, axis=0)        # per-column
-            lv, chunk_skip = _resolve_levels(self.levels, X, e1, spec)
-            sp.set(levels=list(lv) if lv is not None else None,
-                   chunk_skip=bool(chunk_skip))
+        e1, lv, chunk_skip = _prescan(X, self.levels, spec)
         plan = plan_groupby(int(X.shape[0]), sig.num_segments, spec,
                             ncols=ncols, method=self.method, levels=lv)
-        _emit_prescan_stats(X.shape[0], ncols, spec, lv, chunk_skip, plan)
+        _count_groupby(X.shape[0], spec, lv, plan)
         fn = self._tail(plan.method, plan.chunk, plan.buckets, lv,
                         bool(chunk_skip))
+        # the host's enqueue of the compiled tail, as in partial_agg
         with obs_trace.span("groupby.aggregate", method=plan.method,
                             chunk=plan.chunk, buckets=plan.buckets,
                             n=int(X.shape[0]), G=int(sig.num_segments),

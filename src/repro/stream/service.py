@@ -365,7 +365,8 @@ class StreamService:
                 if not line:
                     break
                 try:
-                    req = json.loads(line)
+                    with obs_trace.span("stream.decode", bytes=len(line)):
+                        req = json.loads(line)
                 except json.JSONDecodeError as e:
                     resp = {"ok": False, "error": f"bad json: {e}"}
                 else:

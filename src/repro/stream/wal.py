@@ -387,7 +387,8 @@ class WriteAheadLog:
                 self._f.write(frame)
                 self._f.flush()
                 if self.fsync == "always":
-                    os.fsync(self._f.fileno())
+                    with obs_trace.span("wal.fsync"):
+                        os.fsync(self._f.fileno())
             except OSError as e:
                 raise WalUnavailable(
                     f"WAL append to {self.path} failed: {e}") from e

@@ -238,19 +238,130 @@ def test_plan_groupby_emits_decision_event():
     assert any(row["value"] >= 1 for row in d["repro_plan_total"])
 
 
-def test_groupby_agg_emits_prescan_stats():
+def _query_records(**kw):
+    """Every record of one traced ``groupby_agg`` call over adversarial
+    rows."""
     trace.configure()
     vals, ids, g = _adversarial(n=1001)
-    groupby_agg(vals, ids, g, aggs=("sum",), spec=_SPEC)
-    evs = [r for r in trace.events()
-           if r["name"] == "groupby.prescan_stats"]
-    assert evs
-    at = evs[-1]["attrs"]
+    groupby_agg(vals, ids, g, aggs=("sum",), spec=_SPEC, **kw)
+    return trace.events()
+
+
+def test_groupby_agg_emits_prescan_stats():
+    spans = [r for r in _query_records() if r["kind"] == "span"]
+    by_name = {r["name"]: r for r in spans}
+    at = by_name["groupby.prescan"]["attrs"]
     assert at["n"] == 1001 and at["L"] == _SPEC.L
     assert at["L_eff"] <= at["L"]
-    spans = {r["name"] for r in trace.events() if r["kind"] == "span"}
-    assert {"groupby.prescan", "groupby.aggregate",
-            "groupby.finalize"} <= spans
+    assert not any(r["name"] == "groupby.prescan_stats"
+                   for r in trace.events())
+    assert {"groupby.query", "groupby.columns", "groupby.prescan",
+            "groupby.aggregate", "groupby.finalize"} <= set(by_name)
+    root = by_name["groupby.query"]
+    assert root["parent_id"] is None and root["depth"] == 0
+    assert root["attrs"] == {"G": 17, "method": "auto", "n": 1001,
+                             "ncols": 1}
+    t0, t1 = root["ts"], root["ts"] + root["dur_ns"] * 1e-9
+    for r in spans:
+        if r is not root:
+            assert r["root_id"] == root["span_id"], r["name"]
+            assert t0 <= r["ts"] and \
+                r["ts"] + r["dur_ns"] * 1e-9 <= t1 + 1e-6, r["name"]
+    for name in ("groupby.columns", "groupby.prescan", "groupby.aggregate",
+                 "groupby.finalize"):
+        assert by_name[name]["parent_id"] == root["span_id"], name
+
+
+def test_groupby_records_share_one_root_id():
+    records = [r for r in _query_records() if r["name"].startswith(
+        ("groupby.", "plan."))]
+    (root,) = [r for r in records if r["name"] == "groupby.query"]
+    assert {r["root_id"] for r in records} == {root["span_id"]}
+
+
+@pytest.mark.parametrize("levels, syncs", [("auto", ["lo", "hi",
+                                                     "chunk_skip"]),
+                                           (None, [])])
+def test_host_syncs_of_the_prescan(levels, syncs):
+    records = _query_records(levels=levels)
+    got = [r for r in records if r["name"] == "groupby.host_sync"]
+    assert [r["attrs"]["what"] for r in got] == syncs
+    prescan = [r for r in records if r["name"] == "groupby.prescan"][-1]
+    assert all(r["parent_id"] == prescan["span_id"] for r in got)
+
+
+def test_annotations_carry_span_ids(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    trace.configure(jax_annotations=True)
+    vals, ids, g = _adversarial(n=257)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        groupby_agg(vals, ids, g, aggs=("sum",), spec=_SPEC)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    annotated = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                st = dict(e.stats)
+                if "span_id" in st:
+                    annotated[st["span_id"]] = (e.name, st["root_id"])
+    spans = [r for r in trace.events() if r["kind"] == "span"]
+    assert spans
+    for r in spans:
+        assert annotated[r["span_id"]] == (r["name"], r["root_id"])
+
+
+def test_concurrent_tasks_keep_their_own_span_stacks():
+    import asyncio
+
+    trace.configure()
+
+    async def writer(name):
+        with trace.span(name) as outer:
+            await asyncio.sleep(0.01)
+            with trace.span(name + ".inner") as inner:
+                await asyncio.sleep(0.01)
+            assert inner.parent_id == outer.span_id
+
+    async def main():
+        await asyncio.gather(writer("a"), writer("b"))
+
+    asyncio.run(main())
+    by_name = {r["name"]: r for r in trace.events()}
+    for name in ("a", "b"):
+        outer, inner = by_name[name], by_name[name + ".inner"]
+        assert outer["parent_id"] is None
+        assert outer["root_id"] == outer["span_id"]
+        assert inner["parent_id"] == outer["span_id"]
+        assert inner["root_id"] == outer["span_id"]
+    assert by_name["a"]["root_id"] != by_name["b"]["root_id"]
+
+
+def test_compiles_become_events_named_by_their_span():
+    import jax
+
+    trace.configure()
+    def fresh_probe(x):
+        return jax.lax.sin(x)
+
+    x = jnp.ones(13).block_until_ready()
+    with trace.span("probe") as probe:
+        jax.jit(fresh_probe)(x).block_until_ready()
+    compiles = [r for r in trace.events() if r["name"] == "jax.compile"
+                and "fresh_probe" in r["attrs"]["fun"]]
+    assert sorted(r["attrs"]["phase"] for r in compiles) == sorted(
+        trace.COMPILE_PHASES.values())
+    for r in compiles:
+        assert r["kind"] == "event" and r["parent_id"] == probe.span_id
+        assert r["attrs"]["span"] == "probe"
+        assert r["attrs"]["seconds"] >= 0
+    trace.disable()
+    jax.jit(lambda x: x - 2.0)(x).block_until_ready()
+    assert trace.events() == []
 
 
 def test_calibration_cache_env_guard(tmp_path, caplog):
