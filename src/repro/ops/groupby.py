@@ -37,7 +37,7 @@ from repro.obs import trace as obs_trace
 # this module.
 from repro.ops.partial import (  # noqa: F401
     AGG_KINDS, AggSignature, PartialState, _as_matrix, _build_columns,
-    _compile, _finalize_plans, _minmax_cols, _normalize, agg_name, finalize,
+    _compile, _minmax_cols, _normalize, agg_name, finalize,
     partial_agg)
 
 __all__ = ["groupby_agg", "agg_name", "AGG_KINDS"]

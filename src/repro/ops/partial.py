@@ -527,36 +527,94 @@ def merge_all_jit(states) -> PartialState:
 # stage 3: finalize
 # ---------------------------------------------------------------------------
 
-def _finalize_plans(names, plans, sums, mins, maxs, spec: ReproSpec):
-    """Derive every requested aggregate from the finalized table.
+_SPREAD = ("var", "std")
+
+
+def _finalize_plans(plans, sums, mins, maxs, spec: ReproSpec):
+    """Derive every requested aggregate from the finalized table, in plan
+    order.
 
     Fixed elementwise formulas — pure functions of reproducible inputs, so
     the outputs inherit bit-reproducibility.  Empty groups yield NaN for
     MEAN/VAR/STD (the reduction identity for MIN/MAX, 0 for SUM/COUNT).
+    VAR/STD stop one step short: they yield the two terms of the
+    population variance ``s2/n - mean*mean`` and the non-empty mask, which
+    :func:`_finish_spread` takes in a program of its own.
     """
     nan = jnp.asarray(jnp.nan, spec.dtype)
-    out = {}
-    for name, p in zip(names, plans):
+    out = []
+    for p in plans:
         kind = p[0]
         if kind in ("sum", "count"):
             r = sums[:, p[1]]
         elif kind == "mean":
             s, cnt = sums[:, p[1]], sums[:, p[2]]
             r = jnp.where(cnt > 0, s / jnp.where(cnt > 0, cnt, 1), nan)
-        elif kind in ("var", "std"):
+        elif kind in _SPREAD:
             s, s2, cnt = sums[:, p[1]], sums[:, p[2]], sums[:, p[3]]
             safe = jnp.where(cnt > 0, cnt, 1)
             mean = s / safe
-            r = jnp.maximum(s2 / safe - mean * mean, 0.0)  # population var
-            if kind == "std":
-                r = jnp.sqrt(r)
-            r = jnp.where(cnt > 0, r, nan)
+            r = (s2 / safe, mean * mean, cnt > 0)
         elif kind == "min":
             r = mins[p[1]]
         else:
             r = maxs[p[1]]
-        out[name] = r
+        out.append(r)
     return out
+
+
+def _finish_spread(kinds, terms, spec: ReproSpec):
+    """The last step of each VAR/STD from :func:`_finalize_plans`' terms:
+    ``max(s2/n - mean*mean, 0)``, its root for STD, NaN on empty groups."""
+    nan = jnp.asarray(jnp.nan, spec.dtype)
+    out = []
+    for kind, (ex2, mean2, nonempty) in zip(kinds, terms):
+        r = jnp.maximum(ex2 - mean2, 0.0)               # population var
+        if kind == "std":
+            r = jnp.sqrt(r)
+        out.append(jnp.where(nonempty, r, nan))
+    return out
+
+
+@functools.lru_cache(maxsize=256)
+def _finalizer(sig: AggSignature):
+    """``finalize``'s body for one signature as compiled programs.
+
+    Compiling must not move a bit against the eager execution of the same
+    body (``jax.disable_jit()``), and only one rewrite could: XLA:CPU
+    contracts a product into the add or subtract that consumes it (an
+    FMA, one rounding instead of two) and drops ``optimization_barrier``.
+    Every product of the table's conversion is exact (an integer times a
+    power of two), so contracting it rounds the same.  The one product
+    that rounds, VAR/STD's ``mean * mean``, therefore leaves the first
+    program as a buffer and meets its subtraction in a second one, where
+    no compiler can fuse the two.  A signature without VAR/STD runs one
+    program; jit re-specializes per table shape.
+    """
+    names, _, plans = sig.compiled
+    spec, mm = sig.spec, sig.minmax
+    spread = [i for i, p in enumerate(plans) if p[0] in _SPREAD]
+    kinds = [plans[i][0] for i in spread]
+
+    @jax.jit
+    def exact(table, minv, maxv):
+        sums = acc_mod.finalize(table, spec)                 # (G, ncols)
+        mins = {j: minv[:, i] for i, j in enumerate(mm)}
+        maxs = {j: maxv[:, i] for i, j in enumerate(mm)}
+        return _finalize_plans(plans, sums, mins, maxs, spec)
+
+    @jax.jit
+    def finish(terms):
+        return _finish_spread(kinds, terms, spec)
+
+    def run(state: PartialState):
+        out = exact(state.table, state.minv, state.maxv)
+        if spread:
+            for i, r in zip(spread, finish([out[i] for i in spread])):
+                out[i] = r
+        return dict(zip(names, out))
+
+    return run
 
 
 def finalize(state: PartialState):
@@ -565,18 +623,16 @@ def finalize(state: PartialState):
     A pure function of the canonical state, so two states that are
     bit-identical (one-shot vs any merge tree) finalize to bit-identical
     results — the argument that lets the streaming engine answer queries
-    mid-stream without losing the reproducibility contract.  All of it is
-    timed as the ``groupby.finalize`` span.
+    mid-stream without losing the reproducibility contract.  It runs as
+    one compiled program per signature (two with VAR/STD), bit-identical
+    to the eager execution of the same body (:func:`_finalizer`); which
+    of the two ran is counted as ``repro_groupby_finalize_total{path}``.
+    All of it is timed as the ``groupby.finalize`` span.
     """
-    sig = state.sig
-    spec = sig.spec
-    names, cols, plans = sig.compiled
-    with obs_trace.span("groupby.finalize"):
-        sums = acc_mod.finalize(state.table, spec)           # (G, ncols)
-        mm = sig.minmax
-        mins = {j: state.minv[:, i] for i, j in enumerate(mm)}
-        maxs = {j: state.maxv[:, i] for i, j in enumerate(mm)}
-        return _finalize_plans(names, plans, sums, mins, maxs, spec)
+    path = "eager" if jax.config.jax_disable_jit else "compiled"
+    with obs_trace.span("groupby.finalize", path=path):
+        obs_metrics.counter("repro_groupby_finalize_total", path=path).inc()
+        return _finalizer(state.sig)(state)
 
 
 # ---------------------------------------------------------------------------
@@ -612,9 +668,8 @@ class PartialPipeline:
     construction (integer adds, EFT extraction, float min/max), so XLA
     fusion cannot perturb bits; compiled-vs-eager bit-equality is pinned by
     tests and the stream benchmark's cross-check gate.  (``finalize`` is
-    deliberately *not* jitted anywhere: its float divisions are exact-input
-    -deterministic but not fusion-proof, so it keeps one canonical eager
-    execution path.)
+    compiled on its own, per signature, in a form whose bits fusion cannot
+    move: see :func:`_finalizer`.)
     """
 
     def __init__(self, sig: AggSignature, method: str = "auto",
