@@ -530,23 +530,50 @@ def merge_all_jit(states) -> PartialState:
 _SPREAD = ("var", "std")
 
 
-def _finalize_plans(plans, sums, mins, maxs, spec: ReproSpec):
+def _whole_sums(table: ReproAcc, spec: ReproSpec):
+    """The exact sums of a table whose rows are whole numbers (the ones
+    column, whose sum is COUNT), ``(G, ncols)`` in the accumulator's integer
+    type.
+
+    Extraction leaves whole numbers whole: a level whose ulp is at least 1
+    takes a multiple of that ulp, and the first level whose ulp is at most
+    1 takes the whole rest.  So each level's value ``(C 2^(m-2) + k)
+    2^(e_l - m)`` is a whole number, and their sum is exact in integers
+    below the integer type's range (2^31 rows a group for float32).  A
+    float32 sum could not hold a count past 2^24.
+    """
+    e = table.e1[..., None] - jnp.arange(spec.L, dtype=jnp.int32) * spec.W
+
+    def times_pow2(x, s):
+        return jnp.where(s >= 0, x << jnp.maximum(s, 0),
+                         x >> jnp.maximum(-s, 0))
+
+    return jnp.sum(times_pow2(table.C, e - 2) + times_pow2(table.k,
+                                                           e - spec.m),
+                   axis=-1, dtype=spec.int_dtype)
+
+
+def _finalize_plans(plans, sums, mins, maxs, spec: ReproSpec, whole=None):
     """Derive every requested aggregate from the finalized table, in plan
     order.
 
     Fixed elementwise formulas — pure functions of reproducible inputs, so
     the outputs inherit bit-reproducibility.  Empty groups yield NaN for
     MEAN/VAR/STD (the reduction identity for MIN/MAX, 0 for SUM/COUNT).
-    VAR/STD stop one step short: they yield the two terms of the
-    population variance ``s2/n - mean*mean`` and the non-empty mask, which
-    :func:`_finish_spread` takes in a program of its own.
+    COUNT is the ones column's exact integer sum (``whole``, from
+    :func:`_whole_sums`).  VAR/STD stop one step short: they yield the two
+    terms of the population variance ``s2/n - mean*mean`` and the
+    non-empty mask, which :func:`_finish_spread` takes in a program of its
+    own.
     """
     nan = jnp.asarray(jnp.nan, spec.dtype)
     out = []
     for p in plans:
         kind = p[0]
-        if kind in ("sum", "count"):
+        if kind == "sum":
             r = sums[:, p[1]]
+        elif kind == "count":
+            r = whole[:, p[1]]
         elif kind == "mean":
             s, cnt = sums[:, p[1]], sums[:, p[2]]
             r = jnp.where(cnt > 0, s / jnp.where(cnt > 0, cnt, 1), nan)
@@ -596,12 +623,16 @@ def _finalizer(sig: AggSignature):
     spread = [i for i, p in enumerate(plans) if p[0] in _SPREAD]
     kinds = [plans[i][0] for i in spread]
 
+    counted = any(p[0] == "count" for p in plans)
+
     @jax.jit
     def exact(table, minv, maxv):
         sums = acc_mod.finalize(table, spec)                 # (G, ncols)
         mins = {j: minv[:, i] for i, j in enumerate(mm)}
         maxs = {j: maxv[:, i] for i, j in enumerate(mm)}
-        return _finalize_plans(plans, sums, mins, maxs, spec)
+        return _finalize_plans(
+            plans, sums, mins, maxs, spec,
+            whole=_whole_sums(table, spec) if counted else None)
 
     @jax.jit
     def finish(terms):

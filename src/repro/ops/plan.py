@@ -33,6 +33,11 @@ Two cost sources, in priority order:
 ``chunk`` is picked by the paper's buffer-residency model (§V-C): the
 largest block whose extracted integers fit in the cache budget *beside* the
 (sub-)table, clamped to the overflow-safety bound.
+
+``block_rows`` bounds the working set of the sharded operator's shard
+program (:mod:`repro.ops.sharded`), which aggregates each shard's rows one
+row block at a time: the rows whose transient arrays fit a chip's
+``BLOCK_BUDGET_BYTES`` (:func:`block_rows`).
 """
 from __future__ import annotations
 
@@ -52,9 +57,9 @@ from repro.obs import trace as obs_trace
 
 __all__ = [
     "GroupbyPlan", "PartialPlan", "plan_groupby", "plan_partial",
-    "pick_chunk", "default_chunk", "onehot_block_bound",
-    "scatter_chunk_bound", "pad_and_chunk", "table_bytes", "radix_buckets",
-    "METHODS",
+    "pick_chunk", "block_rows", "BLOCK_BUDGET_BYTES", "default_chunk",
+    "onehot_block_bound", "scatter_chunk_bound", "pad_and_chunk",
+    "table_bytes", "radix_buckets", "METHODS",
 ]
 
 METHODS = ("onehot", "scatter", "sort", "radix", "pallas", "rsum")
@@ -71,6 +76,9 @@ _PARTITION_COST = 8.0  # counting-sort partition: 2 streaming passes per row
 _MERGE_COST = 6.0      # state merge, per table element: demote gather +
                        # where + int add + renorm shift/mask
 _CACHE_BYTES = DEFAULT_CACHE_BYTES
+#: a chip's budget for the transient arrays of one row block of the shard
+#: program: 1 GiB, a sixteenth of a TPU v5e chip's 16 GB
+BLOCK_BUDGET_BYTES = 1 << 30
 
 
 def _clamp_chunk(method: str, chunk: int, spec: ReproSpec) -> int:
@@ -122,6 +130,28 @@ def pick_chunk(method: str, num_segments: int, ncols: int, spec: ReproSpec,
     return int(min(bound, 1 << (int(free // row_bytes).bit_length() - 1)))
 
 
+def block_rows(n: int, ncols: int, spec: ReproSpec, chunk: int, levels=None,
+               budget: int = BLOCK_BUDGET_BYTES) -> int:
+    """Rows of one row block of an ``n``-row shard: the fewest blocks whose
+    transient arrays fit ``budget``, made equal, and rounded up to whole
+    ``chunk`` blocks of the strategy where a chunk fits the budget.
+
+    A block row costs its stacked accumulator columns twice (as built and
+    as the strategy lays them out), its extracted integers (one per live
+    level and column) and its key twice.  Any block size gives the same
+    bits: block tables add exactly (:mod:`repro.ops.sharded`).
+    """
+    n = max(int(n), 1)
+    ncols = max(int(ncols), 1)
+    row = ncols * (2 * np.dtype(spec.dtype).itemsize + window_length(
+        levels, spec) * np.dtype(spec.int_dtype).itemsize) + 2 * 4
+    cap = max(1, int(budget) // row)
+    rows = -(-n // -(-n // cap))
+    if cap >= chunk:
+        rows = -(-rows // chunk) * chunk
+    return min(rows, n)
+
+
 def _emit_plan(plan: "GroupbyPlan", n: int, num_segments: int, ncols: int,
                backend: str, levels) -> "GroupbyPlan":
     """Plan-decision observability: one event + one counter per decision.
@@ -134,7 +164,8 @@ def _emit_plan(plan: "GroupbyPlan", n: int, num_segments: int, ncols: int,
     obs_metrics.counter("repro_plan_total", method=plan.method,
                         source=plan.source).inc()
     obs_trace.event("plan.groupby", method=plan.method, chunk=plan.chunk,
-                    buckets=plan.buckets, source=plan.source,
+                    buckets=plan.buckets, block_rows=plan.block_rows,
+                    source=plan.source,
                     cost_per_row=plan.cost, n=int(n), G=int(num_segments),
                     ncols=int(ncols), backend=backend,
                     levels=list(levels) if levels is not None else None,
@@ -152,6 +183,7 @@ class GroupbyPlan:
     reason: str          # one line of cost-model rationale
     buckets: int = 1     # radix partition fan-out (1 = no partitioning)
     source: str = "model"  # 'model' | 'measured' | 'explicit'
+    block_rows: int = 0  # rows a row block of the shard program holds
 
 
 def plan_groupby(n: int, num_segments: int, spec: ReproSpec, ncols: int = 1,
@@ -185,7 +217,9 @@ def plan_groupby(n: int, num_segments: int, spec: ReproSpec, ncols: int = 1,
         return _emit_plan(
             GroupbyPlan(method, c, 0.0, "explicit request",
                         buckets=buckets if method in ("sort", "radix")
-                        else 1, source="explicit"),
+                        else 1, source="explicit",
+                        block_rows=block_rows(n, ncols, spec, c, levels,
+                                              BLOCK_BUDGET_BYTES)),
             n, num_segments, ncols, backend, levels)
 
     cal = None
@@ -257,7 +291,9 @@ def plan_groupby(n: int, num_segments: int, spec: ReproSpec, ncols: int = 1,
     return _emit_plan(
         GroupbyPlan(best, c, costs[best], reason,
                     buckets=buckets if best in ("sort", "radix") else 1,
-                    source=source),
+                    source=source,
+                    block_rows=block_rows(n, ncols, spec, c, levels,
+                                          BLOCK_BUDGET_BYTES)),
         n, num_segments, ncols, backend, levels)
 
 

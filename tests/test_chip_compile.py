@@ -107,3 +107,39 @@ def test_onehot_contraction_is_highest_precision(one_chip):
     compiled = lowered.compile()
     assert "tpu_custom_call" not in compiled.as_text()
     _fits_one_chip(compiled)
+
+
+def test_sharded_shard_program_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The sharded GROUPBY's shard program at TPC-H SF100 Q1's size (590M
+    rows over the four chips of a 2x2 host): the Pallas kernel inside its
+    row-block loop, the exact all-reduce, and a working set that fits
+    beside the resident rows."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    import repro.kernels.segment_rsum.ops as kernel_ops
+    from repro.ops import plan as plan_mod
+    from repro.ops import sharded
+    from repro.ops.partial import AggSignature
+
+    # the planner and the kernel ask the CPU backend; steer them to the chip
+    monkeypatch.setattr(kernel_ops, "resolve_interpret", lambda _: False)
+    spec = ReproSpec(dtype=jnp.float32, L=2)
+    sig = AggSignature.build(
+        [("sum", 0), ("sum", 1), ("sum", 2), ("sum_prod", 2, 3),
+         ("mean", 0), ("mean", 1), ("mean", 4), ("count",)], 6, spec)
+    n = 590_000_000
+    plan = plan_mod.plan_groupby(n // 4, 7, sig.spec, ncols=sig.ncols,
+                                 backend="tpu", calibration=None)
+    assert plan.method == "pallas"
+    mesh = Mesh(topo.devices[:4], ("data",))
+    program, blocks = sharded._shard_program(sig, mesh, "data", n, plan,
+                                             None)
+    rows = NamedSharding(mesh, PartitionSpec("data"))
+    compiled = program.lower(_shape(rows, (n, 5), jnp.float32),
+                             _shape(rows, (n,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert blocks > 1
+    assert "tpu_custom_call" in text and "all-reduce(" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    sharded._shard_program.cache_clear()
