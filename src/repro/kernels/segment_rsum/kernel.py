@@ -10,11 +10,10 @@ input block.
 
 Multi-column fusion (DESIGN.md §10): the kernel takes a *stacked* input
 (ncols, block_n) with per-column extractor ladders (L, ncols), so one
-one-hot matmul per level accumulates every aggregate column at once —
+one-hot matmul accumulates every aggregate column and level at once —
 SUM / COUNT / MEAN / VAR share a single streaming pass over the rows
-instead of re-streaming per aggregate.  The contraction
-(ncols, block_n) @ (block_n, group_tile) reuses the same one-hot operand
-for all columns.
+instead of re-streaming per aggregate.  The contraction reuses the same
+one-hot operand for all columns, levels and digit planes.
 
 Grid: (group_tiles, input_blocks) — inner axis sequential (accumulation);
 each input block is re-streamed once per group tile, trading HBM reads for
@@ -39,13 +38,23 @@ Layout (what the TPU compiler accepts, DESIGN.md §3.2): rows run along the
 128-lane sub-block at a time; the ids arrive as a ``(1, 128)`` lane vector
 and the one-hot is built transposed, ``(group_tile, 128)``, by comparing
 them against a sublane iota, so no id is ever moved from a lane into a
-sublane.  The extractor ladders and their inverse ulps arrive broadcast to
-lane-dense ``(L, ncols, 128)`` blocks.  The contraction is
-``(ncols, 128) x (group_tile, 128)^T`` at ``precision=HIGHEST``: the MXU's
-default f32 precision rounds operands to bf16, which would drop the low
-bits of the extracted integers.  Scaling ``q`` by ``1/ulp`` before the
-matmul is exact (a power of two), so the MXU sums small integers, and those
-sums are exact while a sub-block holds at most ``2^(m-W+2)`` real rows.
+sublane.  The column count arrives padded to a multiple of 8 sublanes
+(zero rows under zero ladders), and the extractor ladders and their
+inverse ulps arrive broadcast to lane-dense ``(L, ncols, 128)`` blocks.
+
+Digits (DESIGN.md §3.2 item 4): one bf16 MXU pass per sub-block and group
+tile sums every live level and column.  Scaling ``q`` by ``1/ulp`` (a power
+of two) makes it an integer ``v`` with ``|v| <= 2^(W-1)``, and
+:func:`split_digits` writes ``v`` exactly as ``p = ceil(W/9)`` planes, each
+an integer multiple of ``2^(9j)`` whose digit lies in ``[-256, 256]``: 8
+significant bits, exact in bf16, as the 0/1 one-hot is.  The
+``(L * p * ncols, 128) x (group_tile, 128)^T`` contraction at the default
+precision multiplies exactly and accumulates in f32, and every partial sum
+of a plane is a multiple of its ``2^(9j)`` below ``2^(m+1)`` in magnitude
+while a sub-block holds at most ``2^(m-W+2)`` real rows, so it is exact.
+The planes' sums convert to int32 and add into the level's window offsets:
+the same integers the f32 contraction at ``precision=HIGHEST`` gave, in one
+MXU pass in place of up to six per level.
 """
 from __future__ import annotations
 
@@ -58,6 +67,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128      # rows per one-hot matmul: one lane tile
+SUBLANES = 8     # f32 rows per vreg: the column count is padded to this
+DIGIT_BITS = 9   # an integer digit in [-2^8, 2^8] has 8 bits: exact in bf16
 
 
 def exact_block_bound(m: int, W: int) -> int:
@@ -65,12 +76,44 @@ def exact_block_bound(m: int, W: int) -> int:
     return 1 << (m - W + 2)
 
 
+def bf16_digits(W: int) -> int:
+    """Digit planes :func:`split_digits` needs for ``|v| <= 2^(W-1)``:
+    ``ceil(W / 9)`` (1 for W <= 9, 2 for W <= 18, 3 up to f32's W = 21)."""
+    return -(-W // DIGIT_BITS)
+
+
+def split_digits(v, p: int) -> list:
+    """Split integer-valued floats ``v`` with ``|v| <= 2^(9p-1)`` into ``p``
+    planes, top first, that sum to ``v`` exactly.  Plane ``j`` (counted from
+    the bottom) is ``d_j * 2^(9j)`` with an integer ``|d_j| <= 256``.
+
+    ``d_j = floor(r / 2^(9j) + 1/2)`` rounds the remainder ``r`` to the
+    nearest multiple of ``2^(9j)``: scaling by a power of two is exact, and
+    ``|r / 2^(9j)| <= 2^20`` leaves the ``+ 1/2`` exact in f32's 24 bits.
+    The next remainder ``r - d_j 2^(9j)`` is exact and lies in
+    ``(-2^(9j-1), 2^(9j-1)]``.  So the top plane's remainder is ``v``, with
+    ``|v / 2^(9(p-1))| <= 2^8``, and every lower one is at most
+    ``2^(9j+8)``; either way ``|d_j| <= 256``, boundary included.  The
+    bottom plane is the last remainder, at most ``2^8``, or ``v`` itself
+    when ``p = 1``.  ``floor`` and not the fixed extractor
+    ``(r + C) - C``: XLA folds that to ``r`` for a constant ``C``."""
+    planes = []
+    for j in range(p - 1, 0, -1):
+        hi = jnp.floor(v * 2.0 ** -(DIGIT_BITS * j) + 0.5) * 2.0 ** (
+            DIGIT_BITS * j)
+        planes.append(hi)
+        v = v - hi
+    planes.append(v)
+    return planes
+
+
 def _segment_kernel(ids_ref, x_ref, a_ref, iu_ref, k_out, c_out,
-                    k_acc, c_acc, *, L: int, m: int, step: int,
+                    k_acc, c_acc, *, L: int, m: int, p: int, step: int,
                     group_tile: int):
     ni = pl.program_id(1)
     nblk = pl.num_programs(1)
     gi = pl.program_id(0)
+    ncols = x_ref.shape[1]
 
     @pl.when(ni == 0)
     def _init():
@@ -83,19 +126,25 @@ def _segment_kernel(ids_ref, x_ref, a_ref, iu_ref, k_out, c_out,
     def sub_block(s, carry):
         col = pl.multiple_of(s * LANES, LANES)
         ids = ids_ref[0, :, pl.ds(col, LANES)]               # (1, 128) int32
-        onehot_t = (groups == ids).astype(jnp.float32)       # (gt, 128)
+        onehot_t = (groups == ids).astype(jnp.bfloat16)      # (gt, 128)
         r = x_ref[0, :, pl.ds(col, LANES)]                   # (ncols, 128)
+        planes = []
         for l in range(L):
             A = a_ref[l]                                     # per-column
             q = (r + A) - A                                  # EFT, fixed A
             r = r - q
-            # exact: |q / ulp| < 2^(W-1) and a sub-block holds at most
-            # 2^(m-W+2) real rows, so every partial sum is below 2^(m+1)
-            part = jax.lax.dot_general(
-                q * iu_ref[l], onehot_t, (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)          # (ncols, gt)
-            k_acc[l] += part.astype(jnp.int32)
+            # |q / ulp| <= 2^(W-1), boundary included: level 1 admits
+            # |x| < 2^(W-1) ulp (ReproSpec.lattice_e1), each later level
+            # gets a residual of at most half the ulp above, 2^(W-1) of its
+            # own, and rounding to a multiple of ulp never passes the
+            # multiple 2^(W-1) ulp.  W <= 9p: p exact bf16 digit planes
+            planes += split_digits(q * iu_ref[l], p)
+        digits = jnp.concatenate(planes, axis=0).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            digits, onehot_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (L*p*nc, gt)
+        k_acc[...] += part.astype(jnp.int32).reshape(
+            L, p, ncols, group_tile).sum(axis=1)
         return carry
 
     # int32 bounds: under jax_enable_x64 Python ints would make the index
@@ -114,7 +163,7 @@ def _segment_kernel(ids_ref, x_ref, a_ref, iu_ref, k_out, c_out,
 
 
 def segment_rsum_pallas_call(ids3d, x3d, A, inv_ulp, *, L: int, m: int,
-                             group_tile: int, num_group_tiles: int,
+                             W: int, group_tile: int, num_group_tiles: int,
                              interpret: bool):
     """ids3d: (nblk, 1, step) int32; x3d: (nblk, ncols, step) f32, with
     ``step`` a multiple of 128; A/inv_ulp: (L, ncols) f32.  Returns (k, C):
@@ -122,30 +171,36 @@ def segment_rsum_pallas_call(ids3d, x3d, A, inv_ulp, *, L: int, m: int,
     ``group_tile`` a multiple of 128."""
     nblk, ncols, step = x3d.shape
     assert step % LANES == 0 and group_tile % LANES == 0
-    kernel = functools.partial(_segment_kernel, L=L, m=m, step=step,
-                               group_tile=group_tile)
-    ladder = (L, ncols, LANES)
-    A = jnp.broadcast_to(A[:, :, None], ladder)
-    inv_ulp = jnp.broadcast_to(inv_ulp[:, :, None], ladder)
+    # pad the columns to whole sublane tiles, so that each digit plane
+    # stacks aligned; HBM's (8, 128) tiling holds these rows already
+    nc = -(-ncols // SUBLANES) * SUBLANES
+    x3d = jnp.pad(x3d, ((0, 0), (0, nc - ncols), (0, 0)))
+    ladder = (L, nc, LANES)
+    A, inv_ulp = (jnp.broadcast_to(jnp.pad(a, ((0, 0), (0, nc - ncols)))
+                                   [:, :, None], ladder)
+                  for a in (A, inv_ulp))
+    kernel = functools.partial(_segment_kernel, L=L, m=m, p=bf16_digits(W),
+                               step=step, group_tile=group_tile)
     g_total = num_group_tiles * group_tile
-    table = pl.BlockSpec((L, ncols, group_tile), lambda gi, ni: (0, 0, gi))
-    return pl.pallas_call(
+    table = pl.BlockSpec((L, nc, group_tile), lambda gi, ni: (0, 0, gi))
+    k, C = pl.pallas_call(
         kernel,
         grid=(num_group_tiles, nblk),
         in_specs=[
             pl.BlockSpec((1, 1, step), lambda gi, ni: (ni, 0, 0)),
-            pl.BlockSpec((1, ncols, step), lambda gi, ni: (ni, 0, 0)),
+            pl.BlockSpec((1, nc, step), lambda gi, ni: (ni, 0, 0)),
             pl.BlockSpec(ladder, lambda gi, ni: (0, 0, 0)),
             pl.BlockSpec(ladder, lambda gi, ni: (0, 0, 0)),
         ],
         out_specs=[table, table],
         out_shape=[
-            jax.ShapeDtypeStruct((L, ncols, g_total), jnp.int32),
-            jax.ShapeDtypeStruct((L, ncols, g_total), jnp.int32),
+            jax.ShapeDtypeStruct((L, nc, g_total), jnp.int32),
+            jax.ShapeDtypeStruct((L, nc, g_total), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((L, ncols, group_tile), jnp.int32),
-            pltpu.VMEM((L, ncols, group_tile), jnp.int32),
+            pltpu.VMEM((L, nc, group_tile), jnp.int32),
+            pltpu.VMEM((L, nc, group_tile), jnp.int32),
         ],
         interpret=interpret,
     )(ids3d, x3d, A, inv_ulp)
+    return k[:, :ncols], C[:, :ncols]

@@ -2,9 +2,9 @@
 
 ``segment_agg_kernel`` is the fused multi-column entry point: a stacked
 (n, ncols) value matrix aggregates into an accumulator table (G, ncols, L)
-in one streaming pass (one one-hot matmul per level serves every column —
-DESIGN.md §10).  ``segment_rsum_kernel`` is the historical single-column
-API, kept as a thin wrapper.
+in one streaming pass (one bf16 one-hot matmul per 128-row sub-block serves
+every column and live level — DESIGN.md §10).  ``segment_rsum_kernel`` is
+the historical single-column API, kept as a thin wrapper.
 """
 from __future__ import annotations
 
@@ -104,7 +104,7 @@ def segment_agg_kernel(values, segment_ids, num_segments: int,
     n_tiles = -(-num_segments // group_tile)
 
     k, C = segment_rsum_pallas_call(
-        ids2d[:, None, :], x3d, A, inv_ulp, L=nlev, m=spec.m,
+        ids2d[:, None, :], x3d, A, inv_ulp, L=nlev, m=spec.m, W=spec.W,
         group_tile=group_tile, num_group_tiles=n_tiles, interpret=interpret)
     k = k[:, :, :num_segments].transpose(2, 1, 0)         # (G, ncols, nlev)
     C = C[:, :, :num_segments].transpose(2, 1, 0)

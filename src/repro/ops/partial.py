@@ -371,6 +371,15 @@ def _count_groupby(n, spec: ReproSpec, lv, plan):
         spec.L - prescan.window_length(lv, spec))
 
 
+def _kernel_digits(spec: ReproSpec, plan) -> int:
+    """The segment kernel's bf16 digit planes where the plan runs it, else
+    0: the ``bf16_digits`` attribute of the span around its enqueue."""
+    if plan.method != "pallas":
+        return 0
+    from repro.kernels.segment_rsum.kernel import bf16_digits
+    return bf16_digits(spec.W)
+
+
 def _columns(values, keys, cols, spec: ReproSpec, check_finite: bool):
     """The eager front, timed as the ``groupby.columns`` span: the value
     matrix, the int32 key column and the stacked accumulator columns,
@@ -415,7 +424,8 @@ def partial_agg(values, keys, num_segments: int, aggs=("sum",),
         # it is in the device trace
         with obs_trace.span("groupby.aggregate", method=plan.method,
                             chunk=plan.chunk, buckets=plan.buckets,
-                            n=int(X.shape[0]), G=int(num_segments)):
+                            n=int(X.shape[0]), G=int(num_segments),
+                            bf16_digits=_kernel_digits(spec, plan)):
             table = aggregates.segment_table(
                 X, keys, num_segments, spec, method=plan.method, e1=e1,
                 chunk=plan.chunk, levels=lv, chunk_skip=chunk_skip,
@@ -768,6 +778,7 @@ class PartialPipeline:
         with obs_trace.span("groupby.aggregate", method=plan.method,
                             chunk=plan.chunk, buckets=plan.buckets,
                             n=int(X.shape[0]), G=int(sig.num_segments),
+                            bf16_digits=_kernel_digits(spec, plan),
                             compiled=True):
             table, minv, maxv = fn(X, v, keys, e1)
         return PartialState(table=table, minv=minv, maxv=maxv,

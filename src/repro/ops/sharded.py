@@ -56,7 +56,8 @@ from repro.core.types import ReproSpec
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.ops.partial import (AggSignature, PartialState, _as_matrix,
-                               _build_columns, _count_groupby, finalize)
+                               _build_columns, _count_groupby, _kernel_digits,
+                               finalize)
 from repro.ops.plan import plan_groupby
 
 __all__ = ["sharded_groupby_agg", "sharded_partial_agg"]
@@ -181,7 +182,8 @@ def _sharded_partial(values, keys, num_segments, aggs, spec, mesh, axis_name,
     if built:
         obs_metrics.counter("repro_groupby_shard_programs_total").inc()
     with obs_trace.span("groupby.shard_program", built=int(built),
-                        shards=nshards, blocks=nblocks):
+                        shards=nshards, blocks=nblocks,
+                        bf16_digits=_kernel_digits(spec, plan)):
         k, C, e1, mins, maxs = program(values, keys)
     state = PartialState(table=ReproAcc(k=k, C=C, e1=e1), minv=mins,
                          maxv=maxs, rows=jnp.asarray(n, jnp.int32), sig=sig)

@@ -25,7 +25,7 @@ from repro.kernels.rsum.ops import rsum_table
 from repro.kernels.segment_rsum.ops import segment_agg_kernel
 
 Q1_ROWS = 1 << 20        # a Q1-sized batch
-Q1_COLS = 6              # Q1's accumulator columns
+Q1_COLS = 6              # accumulator columns of a wider Q1 signature
 HBM_BYTES = 16 * 2**30   # one v5e chip
 
 
@@ -68,11 +68,12 @@ def _fits_one_chip(compiled):
     assert 0 < total < HBM_BYTES
 
 
+@pytest.mark.parametrize("ncols", [1, 5, Q1_COLS])   # Q1 stacks 5 columns
 @pytest.mark.parametrize("groups", [6, 4096])
 @pytest.mark.parametrize("L", [2, 3])
-def test_segment_kernel_compiles_for_v5e(one_chip, groups, L):
+def test_segment_kernel_compiles_for_v5e(one_chip, groups, L, ncols):
     compiled = segment_agg_kernel.lower(
-        _shape(one_chip, (Q1_ROWS, Q1_COLS), jnp.float32),
+        _shape(one_chip, (Q1_ROWS, ncols), jnp.float32),
         _shape(one_chip, (Q1_ROWS,), jnp.int32), groups,
         ReproSpec(dtype=jnp.float32, L=L), interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
