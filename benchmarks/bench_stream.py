@@ -9,14 +9,11 @@ incremental aggregation).  Parts:
   persistent XLA compilation cache (:mod:`repro.compile_cache`).  The
   subprocesses run before this process touches a device, because a chip
   belongs to one process at a time;
-* **sustained** — concurrent writers through the asyncio NDJSON service,
-  three configurations side by side in one run on one machine:
-  **serialized** (the PR-5 shape: eager ``partial_agg`` under one global
-  lock), **pipelined** (compiled prepare on a thread pool outside the
-  locks, commit serialized per store), and **sharded** (pipelined over a
-  :class:`ShardedStreamStore`).  The scaling assertion — pipelined >=
-  1.5x serialized with 4 writers — runs here, after each path's own
-  bitwise gate.
+* **sustained** — direct in-process ingest, then concurrent writers
+  through the asyncio NDJSON service (prepare on a thread pool outside the
+  locks, commit serialized per store), over one store, over a
+  :class:`ShardedStreamStore` and over a store restored from a snapshot.
+  Each path is gated on its own bits.
 
 * **durability** — WAL-on vs WAL-off sustained ingest (gated: fsync'd
   logging within :data:`MAX_WAL_OVERHEAD` of WAL-off), recovery time from
@@ -25,8 +22,8 @@ incremental aggregation).  Parts:
   :class:`~repro.stream.ReplicatedStore`.
 
 ``cross_check`` runs FIRST: the streamed state (1, 7 and 64 permuted
-micro-batches, a snapshot/restart mid-stream, the concurrent pipelined
-service, and the sharded store under both policies) must fingerprint
+micro-batches, a snapshot/restart mid-stream, the service with concurrent
+writers, and the sharded store under both policies) must fingerprint
 bit-identically to the one-shot ``groupby_agg`` before any number is
 recorded — a benchmark of a non-reproducible stream would be measuring
 the wrong engine.  Each sustained configuration is *additionally* gated
@@ -85,14 +82,13 @@ def _want(v, k) -> dict:
 # step 1: the bitwise gate
 # ---------------------------------------------------------------------------
 
-def _pipelined_service_fingerprints(store, v, k, writers: int,
-                                    batch: int) -> dict:
-    """Drive every row through a pipelined in-process service with
-    ``writers`` concurrent tasks; return the store fingerprints."""
+def _service_fingerprints(store, v, k, writers: int, batch: int) -> dict:
+    """Drive every row through an in-process service with ``writers``
+    concurrent tasks; return the store fingerprints."""
     from repro.stream import StreamService
 
     async def run():
-        service = StreamService(store, pipelined=True, max_workers=writers)
+        service = StreamService(store, max_workers=writers)
         spans = np.array_split(np.arange(v.shape[0]), writers)
 
         async def writer(rows):
@@ -133,10 +129,10 @@ def cross_check(n: int = 20001) -> str:
         got = store.fingerprints()
         assert got == want, \
             f"stream(restart) != one-shot: {got} vs {want}"
-    # the pipelined service: concurrent prepares, scrambled commit order
-    got = _pipelined_service_fingerprints(StreamStore(G, aggs=AGGS),
-                                          v, k, writers=4, batch=1024)
-    assert got == want, f"pipelined service != one-shot: {got} vs {want}"
+    # the service: concurrent prepares, scrambled commit order
+    got = _service_fingerprints(StreamStore(G, aggs=AGGS), v, k, writers=4,
+                                batch=1024)
+    assert got == want, f"service != one-shot: {got} vs {want}"
     # sharded stores, both assignment policies
     for shards, policy in ((2, "round_robin"), (4, "key_hash")):
         store = ShardedStreamStore(G, aggs=AGGS, num_shards=shards,
@@ -148,7 +144,7 @@ def cross_check(n: int = 20001) -> str:
         assert got == want, (f"sharded({shards},{policy}) != one-shot: "
                              f"{got} vs {want}")
     print("bitwise cross-check OK (1/7/64 permuted batches, restart, "
-          "pipelined service, sharded x2 policies)")
+          "service, sharded x2 policies)")
     return "ok"
 
 
@@ -288,12 +284,6 @@ def _run_service_ingest(store, v, k, writers: int, batch: int,
     return asyncio.run(run())
 
 
-#: CI scaling gate: the pipelined service must beat the serialized PR-5
-#: configuration by at least this factor with 4 concurrent writers (the
-#: acceptance target is 2x; 1.5x here keeps CI robust to noisy runners)
-MIN_PIPELINE_SPEEDUP = 1.5
-
-
 def run_sustained(quick: bool = True, writers: int = 4) -> dict:
     n = 2**17 if quick else 2**21
     batch = 2048 if quick else 8192
@@ -306,34 +296,27 @@ def run_sustained(quick: bool = True, writers: int = 4) -> dict:
         assert got == want, f"{label} != one-shot: {got} vs {want}"
         return "ok"
 
-    # direct in-process ingest (engine cost, no protocol), both stores
-    for label, compiled in (("direct_serialized", False), ("direct", True)):
-        store = StreamStore(G, aggs=AGGS, compiled=compiled)
-        t0 = time.perf_counter()
-        for lo in range(0, n, batch):
-            store.ingest(v[lo:lo + batch], k[lo:lo + batch])
-        store.query()
-        out[f"{label}_rows_per_s"] = n / (time.perf_counter() - t0)
-        gate(store, label)
-
-    # the side-by-side: three service configurations, same rows, same
-    # writers, same machine, one run.  Each is gated on its own bits.
-    # serialized = the PR-5 shape: eager partial_agg, one global lock.
-    store = StreamStore(G, aggs=AGGS, compiled=False)
-    dt = _run_service_ingest(store, v, k, writers, batch, pipelined=False)
-    out["service_serialized_rows_per_s"] = n / dt
-    out["service_serialized_cross_check"] = gate(store, "serialized service")
-
-    # pipelined: compiled prepare on the pool, per-store commit lock
+    # direct in-process ingest (engine cost, no protocol)
     store = StreamStore(G, aggs=AGGS)
-    dt = _run_service_ingest(store, v, k, writers, batch, pipelined=True)
-    out["service_pipelined_rows_per_s"] = n / dt
-    out["service_pipelined_cross_check"] = gate(store, "pipelined service")
+    t0 = time.perf_counter()
+    for lo in range(0, n, batch):
+        store.ingest(v[lo:lo + batch], k[lo:lo + batch])
+    store.query()
+    out["direct_rows_per_s"] = n / (time.perf_counter() - t0)
+    gate(store, "direct")
 
-    # sharded + pipelined: per-shard commit locks
+    # the service configurations, same rows, same writers, same machine,
+    # one run.  Each is gated on its own bits.
+    # one store: prepare on the pool, one commit lock
+    store = StreamStore(G, aggs=AGGS)
+    dt = _run_service_ingest(store, v, k, writers, batch)
+    out["service_store_rows_per_s"] = n / dt
+    out["service_store_cross_check"] = gate(store, "service")
+
+    # sharded: per-shard commit locks
     store = ShardedStreamStore(G, aggs=AGGS, num_shards=4,
                                policy="round_robin")
-    dt = _run_service_ingest(store, v, k, writers, batch, pipelined=True)
+    dt = _run_service_ingest(store, v, k, writers, batch)
     out["service_sharded_rows_per_s"] = n / dt
     out["service_sharded_cross_check"] = gate(store, "sharded service")
 
@@ -343,31 +326,22 @@ def run_sustained(quick: bool = True, writers: int = 4) -> dict:
         seed_store.ingest(v, k)
         seed_store.snapshot(d)
         restored = StreamStore.restore(d)
-        dt = _run_service_ingest(restored, v, k, writers, batch,
-                                 pipelined=True)
+        dt = _run_service_ingest(restored, v, k, writers, batch)
         out["service_persistent_rows_per_s"] = n / dt
         restored.query()
 
-    out["pipeline_speedup"] = (out["service_pipelined_rows_per_s"] /
-                               out["service_serialized_rows_per_s"])
     out["peak_rss_mb"] = resource.getrusage(
         resource.RUSAGE_SELF).ru_maxrss / 1024.0
 
     print(f"\n== sustained ingest (n={n}, batch={batch}, "
           f"{writers} writers) ==")
-    print(f"  direct serialized     "
-          f"{out['direct_serialized_rows_per_s']:12,.0f} rows/s")
-    print(f"  direct pipelined      {out['direct_rows_per_s']:12,.0f} rows/s")
-    for m in ("serialized", "pipelined", "sharded", "persistent"):
+    print(f"  direct              {out['direct_rows_per_s']:12,.0f} rows/s")
+    for m in ("store", "sharded", "persistent"):
         key = f"service_{m}_rows_per_s"
         check = out.get(f"service_{m}_cross_check", "-")
         print(f"  service {m:11} {out[key]:12,.0f} rows/s  "
               f"[cross-check {check}]")
-    print(f"  pipelined / serialized: {out['pipeline_speedup']:.2f}x")
     print(f"  peak RSS {out['peak_rss_mb']:.0f} MB")
-    assert out["pipeline_speedup"] >= MIN_PIPELINE_SPEEDUP, (
-        f"pipelined service only {out['pipeline_speedup']:.2f}x the "
-        f"serialized service (gate: {MIN_PIPELINE_SPEEDUP}x)")
     return out
 
 

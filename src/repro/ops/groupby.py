@@ -32,13 +32,7 @@ import numpy as np
 
 from repro.core.types import ReproSpec
 from repro.obs import trace as obs_trace
-# Compilation/finalization helpers live in repro.ops.partial now; re-exported
-# here because sharded.py and external callers historically import them from
-# this module.
-from repro.ops.partial import (  # noqa: F401
-    AGG_KINDS, AggSignature, PartialState, _as_matrix, _build_columns,
-    _compile, _minmax_cols, _normalize, agg_name, finalize,
-    partial_agg)
+from repro.ops.partial import AGG_KINDS, agg_name, finalize, partial_agg
 
 __all__ = ["groupby_agg", "agg_name", "AGG_KINDS"]
 

@@ -38,12 +38,12 @@ from repro.core.accumulator import ReproAcc
 from repro.core.types import FLOAT_SPECS, ReproSpec
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.ops.plan import plan_groupby
+from repro.ops.plan import GroupbyPlan, plan_groupby
 
 __all__ = [
-    "AGG_KINDS", "AggSignature", "PartialState", "PartialPipeline",
-    "agg_name", "partial_agg", "merge", "merge_all", "merge_all_jit",
-    "finalize", "empty_partial", "pipeline_for", "state_nbytes",
+    "AGG_KINDS", "AggSignature", "PartialState", "agg_name", "partial_agg",
+    "merge", "merge_all", "merge_all_jit", "finalize", "empty_partial",
+    "state_nbytes",
 ]
 
 AGG_KINDS = ("sum", "count", "mean", "var", "std", "min", "max", "sum_prod")
@@ -395,6 +395,51 @@ def _columns(values, keys, cols, spec: ReproSpec, check_finite: bool):
     return v, keys, X
 
 
+#: the plan of a signature without accumulator columns (MIN/MAX only):
+#: there is no table to plan a strategy for
+_NO_COLUMNS = GroupbyPlan(method="none", chunk=0, cost=0.0,
+                          reason="MIN/MAX only: no accumulator column")
+
+
+def _aggregate(X, v, keys, num_segments: int, sig: AggSignature, plan, e1,
+               levels, chunk_skip: bool):
+    """The strategy body of a partial aggregate: the accumulator table of
+    the columns ``X`` by ``keys`` on the lattice ``e1`` (all zeros where
+    the signature has no accumulator column) and the stacked MIN/MAX
+    columns of the values ``v``: ``(table, minv, maxv)``.
+
+    The one body :func:`partial_agg` compiles (:func:`_tail`) and the
+    shard program traces per row block.  Every op is exact (integer adds,
+    EFT extraction, float min/max), so compiling it moves no bit against
+    its eager run under ``jax.disable_jit()``.
+    """
+    spec, mm = sig.spec, sig.minmax
+    if X.shape[1]:
+        table = aggregates.segment_table(
+            X, keys, num_segments, spec, method=plan.method, e1=e1,
+            chunk=plan.chunk, levels=levels, chunk_skip=chunk_skip,
+            num_buckets=plan.buckets if plan.method in ("sort", "radix")
+            else None)
+    else:
+        table = acc_mod.zeros(spec, (num_segments, 0))
+    if mm:
+        m = jnp.stack([v[:, j] for j in mm], axis=1)
+        minv = jax.ops.segment_min(m, keys, num_segments)
+        maxv = jax.ops.segment_max(m, keys, num_segments)
+    else:
+        minv = maxv = jnp.zeros((num_segments, 0), spec.dtype)
+    return table, minv, maxv
+
+
+@functools.lru_cache(maxsize=256)
+def _tail(sig: AggSignature, plan, levels, chunk_skip: bool):
+    """:func:`_aggregate` for one signature and plan decision, compiled;
+    jit re-specializes per row count."""
+    return jax.jit(functools.partial(
+        _aggregate, num_segments=sig.num_segments, sig=sig, plan=plan,
+        levels=levels, chunk_skip=chunk_skip))
+
+
 def partial_agg(values, keys, num_segments: int, aggs=("sum",),
                 spec: ReproSpec | None = None, method: str = "auto",
                 chunk: int | None = None, levels="auto",
@@ -409,6 +454,12 @@ def partial_agg(values, keys, num_segments: int, aggs=("sum",),
     ``required_e1``); :func:`merge` aligns mismatched lattices exactly, so
     any micro-batching of the rows merges to the one-shot state bit for
     bit.
+
+    The front runs eagerly, because its outputs are the compiled tail's
+    static keys: the column build, the prescan (its host syncs), the plan.
+    The strategy tail is one cached program per (signature, plan, level
+    window, chunk_skip) decision (:func:`_tail`), so a repeated batch shape
+    compiles nothing.
     """
     sig = AggSignature.build(aggs, num_segments, spec)
     spec = sig.spec
@@ -420,33 +471,16 @@ def partial_agg(values, keys, num_segments: int, aggs=("sum",),
         plan = plan_groupby(int(X.shape[0]), num_segments, spec, ncols=ncols,
                             method=method, chunk=chunk, levels=lv)
         _count_groupby(X.shape[0], spec, lv, plan)
-        # times the host's enqueue of the strategy: the device's time for
-        # it is in the device trace
-        with obs_trace.span("groupby.aggregate", method=plan.method,
-                            chunk=plan.chunk, buckets=plan.buckets,
-                            n=int(X.shape[0]), G=int(num_segments),
-                            bf16_digits=_kernel_digits(spec, plan)):
-            table = aggregates.segment_table(
-                X, keys, num_segments, spec, method=plan.method, e1=e1,
-                chunk=plan.chunk, levels=lv, chunk_skip=chunk_skip,
-                num_buckets=plan.buckets if plan.method in ("sort", "radix")
-                else None)
     else:
-        table = acc_mod.zeros(spec, (num_segments, 0))
-
-    mm = sig.minmax
-    if mm:
-        with obs_trace.span("groupby.minmax", ncols=len(mm)):
-            minv = jnp.stack(
-                [jax.ops.segment_min(v[:, j], keys, num_segments)
-                 for j in mm], axis=1)
-            maxv = jnp.stack(
-                [jax.ops.segment_max(v[:, j], keys, num_segments)
-                 for j in mm], axis=1)
-    else:
-        minv = jnp.zeros((num_segments, 0), spec.dtype)
-        maxv = jnp.zeros((num_segments, 0), spec.dtype)
-
+        e1, lv, chunk_skip, plan = None, None, False, _NO_COLUMNS
+    # times the host's enqueue of the strategy: the device's time for it is
+    # in the device trace
+    with obs_trace.span("groupby.aggregate", method=plan.method,
+                        chunk=plan.chunk, buckets=plan.buckets,
+                        n=int(X.shape[0]), G=int(num_segments),
+                        bf16_digits=_kernel_digits(spec, plan)):
+        table, minv, maxv = _tail(sig, plan, lv, bool(chunk_skip))(
+            X, v, keys, e1=e1)
     return PartialState(table=table, minv=minv, maxv=maxv,
                         rows=jnp.asarray(v.shape[0], jnp.int32), sig=sig)
 
@@ -677,7 +711,7 @@ def finalize(state: PartialState):
 
 
 # ---------------------------------------------------------------------------
-# the compiled partial pipeline (streaming prepare stage)
+# backpressure accounting
 # ---------------------------------------------------------------------------
 
 def state_nbytes(state: PartialState) -> int:
@@ -685,111 +719,3 @@ def state_nbytes(state: PartialState) -> int:
     return sum(int(np.asarray(x).nbytes)
                for x in (state.table.k, state.table.C, state.table.e1,
                          state.minv, state.maxv, state.rows))
-
-
-class PartialPipeline:
-    """:func:`partial_agg` specialized to one fixed :class:`AggSignature`,
-    with the jax-heavy tail compiled and cached.
-
-    Eager ``partial_agg`` re-traces its strategies on every call — fine for
-    one-shot queries, ruinous for a stream ingesting thousands of
-    same-shaped micro-batches (XLA compilation dominated the measured batch
-    cost ~10:1).  A store has exactly one signature and sees repeating
-    batch shapes, so it is the natural place to amortize compilation; this
-    class is that amortization, shared across stores (and across the shards
-    of a :class:`repro.stream.ShardedStreamStore`) via :func:`pipeline_for`.
-
-    Staging mirrors ``partial_agg`` exactly: the host-driven front (column
-    build, per-column ``required_e1``, the concrete-input prescan, planner
-    dispatch, the opt-in finite check) stays eager because its outputs are
-    *static* compilation keys; the tail — ``segment_table`` plus the
-    stacked MIN/MAX segment reductions — is one jitted function per
-    (method, chunk, buckets, level window, chunk_skip) decision, with jit
-    itself re-specializing per batch shape.  Every tail op is exact by
-    construction (integer adds, EFT extraction, float min/max), so XLA
-    fusion cannot perturb bits; compiled-vs-eager bit-equality is pinned by
-    tests and the stream benchmark's cross-check gate.  (``finalize`` is
-    compiled on its own, per signature, in a form whose bits fusion cannot
-    move: see :func:`_finalizer`.)
-    """
-
-    def __init__(self, sig: AggSignature, method: str = "auto",
-                 levels="auto", check_finite: bool = False):
-        self.sig = sig
-        self.method = method
-        self.levels = tuple(levels) if isinstance(levels, list) else levels
-        self.check_finite = check_finite
-        self._tails: dict = {}
-
-    def _tail(self, method: str, chunk: int, buckets: int, levels,
-              chunk_skip: bool):
-        key = (method, chunk, buckets, levels, chunk_skip)
-        fn = self._tails.get(key)
-        if fn is not None:
-            return fn
-        sig, spec, mm = self.sig, self.sig.spec, self.sig.minmax
-
-        def tail(X, v, keys, e1):
-            table = aggregates.segment_table(
-                X, keys, sig.num_segments, spec, method=method, e1=e1,
-                chunk=chunk, levels=levels, chunk_skip=chunk_skip,
-                num_buckets=buckets if method in ("sort", "radix") else None)
-            if mm:
-                minv = jnp.stack(
-                    [jax.ops.segment_min(v[:, j], keys, sig.num_segments)
-                     for j in mm], axis=1)
-                maxv = jnp.stack(
-                    [jax.ops.segment_max(v[:, j], keys, sig.num_segments)
-                     for j in mm], axis=1)
-            else:
-                minv = jnp.zeros((sig.num_segments, 0), spec.dtype)
-                maxv = jnp.zeros((sig.num_segments, 0), spec.dtype)
-            return table, minv, maxv
-
-        # setdefault: two pool threads may race to build; one wrapper wins
-        return self._tails.setdefault(key, jax.jit(tail))
-
-    @property
-    def compiled_variants(self) -> int:
-        """Distinct plan decisions compiled so far (observability)."""
-        return len(self._tails)
-
-    def __call__(self, values, keys) -> PartialState:
-        """Aggregate one batch — bit-identical to ``partial_agg`` with this
-        pipeline's configuration, amortizing compilation across calls."""
-        sig = self.sig
-        spec = sig.spec
-        v, keys, X = _columns(values, keys, sig.compiled[1], spec,
-                              self.check_finite)
-        ncols = X.shape[1]
-        if not ncols:
-            # min/max-only stores are rare and tiny: keep one code path
-            return partial_agg(values, keys, sig.num_segments, aggs=sig.aggs,
-                               spec=spec, method=self.method,
-                               levels=self.levels,
-                               check_finite=self.check_finite)
-        e1, lv, chunk_skip = _prescan(X, self.levels, spec)
-        plan = plan_groupby(int(X.shape[0]), sig.num_segments, spec,
-                            ncols=ncols, method=self.method, levels=lv)
-        _count_groupby(X.shape[0], spec, lv, plan)
-        fn = self._tail(plan.method, plan.chunk, plan.buckets, lv,
-                        bool(chunk_skip))
-        # the host's enqueue of the compiled tail, as in partial_agg
-        with obs_trace.span("groupby.aggregate", method=plan.method,
-                            chunk=plan.chunk, buckets=plan.buckets,
-                            n=int(X.shape[0]), G=int(sig.num_segments),
-                            bf16_digits=_kernel_digits(spec, plan),
-                            compiled=True):
-            table, minv, maxv = fn(X, v, keys, e1)
-        return PartialState(table=table, minv=minv, maxv=maxv,
-                            rows=jnp.asarray(v.shape[0], jnp.int32), sig=sig)
-
-
-@functools.lru_cache(maxsize=64)
-def pipeline_for(sig: AggSignature, method: str = "auto", levels="auto",
-                 check_finite: bool = False) -> PartialPipeline:
-    """The shared :class:`PartialPipeline` for a configuration.  Stores and
-    shards with equal (signature, method, levels, check_finite) reuse one
-    pipeline — and therefore one set of compiled executables."""
-    return PartialPipeline(sig, method=method, levels=levels,
-                           check_finite=check_finite)

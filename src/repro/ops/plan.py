@@ -317,7 +317,7 @@ class PartialPlan:
     table coalesces aggressively.
 
     ``pipeline`` is the ingest pipeline width: how many concurrent
-    ``prepare`` workers the pipelined stream service should run.  The pure
+    ``prepare`` workers the stream service should run.  The pure
     per-batch aggregation parallelizes perfectly (DESIGN.md §15); the
     amortized merge (``merge_rows / coalesce`` row-equivalents per batch)
     is the serialized stage, so by Amdahl the useful width is the

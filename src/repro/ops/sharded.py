@@ -50,14 +50,14 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import accumulator as acc_mod
-from repro.core import aggregates, collectives
+from repro.core import collectives
 from repro.core.accumulator import ReproAcc
 from repro.core.types import ReproSpec
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.ops.partial import (AggSignature, PartialState, _as_matrix,
-                               _build_columns, _count_groupby, _kernel_digits,
-                               finalize)
+from repro.ops.partial import (AggSignature, PartialState, _aggregate,
+                               _as_matrix, _build_columns, _count_groupby,
+                               _kernel_digits, finalize)
 from repro.ops.plan import plan_groupby
 
 __all__ = ["sharded_groupby_agg", "sharded_partial_agg"]
@@ -113,18 +113,13 @@ def _shard_program(sig: AggSignature, mesh, axis_name: str, n: int, plan,
         def add_block(b, carry):
             k, C, mins, maxs = carry
             vb, ib = block(v_s, id_s, b)
+            t, bmin, bmax = _aggregate(
+                _build_columns(vb, cols, spec), vb, ib, G + 1, sig, plan,
+                e1 if ncols else None, levels, False)
             if ncols:
-                t = aggregates.segment_table(
-                    _build_columns(vb, cols, spec), ib, G + 1, spec,
-                    method=plan.method, e1=e1, chunk=plan.chunk,
-                    levels=levels,
-                    num_buckets=plan.buckets
-                    if plan.method in ("sort", "radix") else None)
                 k, C = acc_mod.renorm(k + t.k, C + t.C, spec)
             if mm:
-                m = jnp.stack([vb[:, j] for j in mm], axis=1)
-                mins = jnp.minimum(mins, jax.ops.segment_min(m, ib, G + 1))
-                maxs = jnp.maximum(maxs, jax.ops.segment_max(m, ib, G + 1))
+                mins, maxs = jnp.minimum(mins, bmin), jnp.maximum(maxs, bmax)
             return k, C, mins, maxs
 
         z = jnp.zeros((G + 1, ncols, spec.L), spec.int_dtype)

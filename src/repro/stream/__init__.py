@@ -13,7 +13,7 @@ cashes that in for unbounded streams (DESIGN.md §14.3–§14.5):
   shard stores (round-robin or key-hash batch assignment) whose query-time
   ``merge_all`` is bit-identical to a single store, by the same algebra;
 * :mod:`repro.stream.service` — an asyncio NDJSON ingest/query endpoint
-  with pipelined ingest: the pure ``prepare`` stage runs on a thread pool
+  with concurrent ingest: the pure ``prepare`` stage runs on a thread pool
   outside the locks, only the tiny ``commit`` serializes (per shard), and
   backpressure bounds in-flight memory.  Any interleaving of concurrent
   writers yields the bit-identical state — the lock picks an order, the

@@ -19,11 +19,7 @@ cases the batch is admitted exactly once or not at all, never dropped
 or double-counted.  ``query``/``fingerprints``/``snapshot``/``stats``
 drain in-flight prepares and take every shard lock first, so their
 contracts (all acknowledged rows included, consistent counters) are
-exactly the serialized service's.
-
-``pipelined=False`` restores the PR-5 behavior — one global lock around
-whole store calls — and is kept both as the measured baseline in
-``bench_stream.py`` and as the zero-thread fallback.
+those of a service that runs one call at a time.
 
 Wire protocol: newline-delimited JSON (NDJSON) over a plain socket —
 stdlib only, trivially driven from tests and ``examples/``:
@@ -74,15 +70,13 @@ class Backpressure(RuntimeError):
 
 
 class StreamService:
-    """Pipelined async facade over a :class:`StreamStore` /
+    """Async facade over a :class:`StreamStore` /
     :class:`ShardedStreamStore` (or any object with the shard interface:
     ``_prepare_parts`` / ``_commit_part`` / ``num_shards`` plus
     ``query/fingerprints/snapshot``).
 
     Args:
       store: the underlying store.
-      pipelined: run ``prepare`` on an executor outside the locks
-        (default).  ``False`` = PR-5 global-lock behavior.
       max_workers: prepare-pool size; default asks the store's planner
         (``pipeline_width`` of the first batch seen).
       inflight_budget: bytes of admitted-but-uncommitted batches allowed
@@ -103,8 +97,7 @@ class StreamService:
         retries with the same ``(client, seq)`` tag and is deduplicated.
     """
 
-    def __init__(self, store, pipelined: bool = True,
-                 max_workers: Optional[int] = None,
+    def __init__(self, store, max_workers: Optional[int] = None,
                  inflight_budget: int = DEFAULT_INFLIGHT_BUDGET,
                  backpressure: str = "wait", max_retries: int = 0,
                  retry_backoff_s: float = 0.05,
@@ -114,7 +107,6 @@ class StreamService:
                 f"backpressure must be 'wait' or 'reject', got "
                 f"{backpressure!r}")
         self.store = store
-        self.pipelined = bool(pipelined)
         self.backpressure = backpressure
         self.max_retries = int(max_retries)
         self.retry_backoff_s = float(retry_backoff_s)
@@ -122,21 +114,13 @@ class StreamService:
         self._budget = int(inflight_budget)
         self._max_workers = max_workers
         self._executor: Optional[ThreadPoolExecutor] = None
-        self._lock = asyncio.Lock()  # serialized mode: the one global lock
         nshards = getattr(store, "num_shards", 1)
         self._locks = [asyncio.Lock() for _ in range(nshards)]
         self._cond = asyncio.Condition()
         self._inflight = 0
         self._inflight_bytes = 0
 
-    # -- serialized mode (PR-5): global lock around whole store calls ------
-
-    async def _run(self, fn, *args):
-        loop = asyncio.get_running_loop()
-        async with self._lock:
-            return await loop.run_in_executor(None, fn, *args)
-
-    # -- pipelined mode ----------------------------------------------------
+    # -- the prepare pool, backpressure and the locks ----------------------
 
     def _pool(self, batch_rows: int) -> ThreadPoolExecutor:
         """Prepare pool, sized lazily: the planner's pipeline width for the
@@ -188,7 +172,7 @@ class StreamService:
         """Run ``fn`` with the store quiesced: every in-flight prepare
         committed (drain) and every shard lock held (in index order, so two
         exclusive ops can't deadlock).  This is how ``query`` / ``snapshot``
-        / ``stats`` keep their serialized-service contracts."""
+        / ``stats`` keep the contracts of a one-call-at-a-time service."""
         async with self._cond:
             await self._cond.wait_for(lambda: self._inflight == 0)
         loop = asyncio.get_running_loop()
@@ -197,7 +181,7 @@ class StreamService:
                 await stack.enter_async_context(lock)
             return await loop.run_in_executor(None, fn, *args)
 
-    async def _ingest_pipelined(self, values, keys, meta=None) -> dict:
+    async def _ingest_once(self, values, keys, meta) -> dict:
         loop = asyncio.get_running_loop()
         v = np.asarray(values)
         k = np.asarray(keys)
@@ -233,16 +217,6 @@ class StreamService:
 
     # -- operations --------------------------------------------------------
 
-    async def _ingest_once(self, values, keys, meta) -> dict:
-        if self.pipelined:
-            return await self._ingest_pipelined(values, keys, meta)
-        if meta is not None:
-            return await self._run(
-                lambda: self.store.ingest(values, keys,
-                                          client=meta["client"],
-                                          seq=meta["cseq"]))
-        return await self._run(self.store.ingest, values, keys)
-
     async def ingest(self, values, keys, client=None, seq=None) -> dict:
         t0 = time.perf_counter()
         meta = _delivery_meta(client, seq)
@@ -269,19 +243,15 @@ class StreamService:
             time.perf_counter() - t0)
         return out
 
-    async def _guarded(self, fn, *args):
-        return await (self._exclusive(fn, *args) if self.pipelined
-                      else self._run(fn, *args))
-
     async def query(self) -> dict:
-        out = await self._guarded(self.store.query)
+        out = await self._exclusive(self.store.query)
         return {k: np.asarray(v).tolist() for k, v in out.items()}
 
     async def fingerprints(self) -> dict:
-        return await self._guarded(self.store.fingerprints)
+        return await self._exclusive(self.store.fingerprints)
 
     async def snapshot(self, directory: str) -> str:
-        return await self._guarded(self.store.snapshot, directory)
+        return await self._exclusive(self.store.snapshot, directory)
 
     async def stats(self) -> dict:
         # one closure, run with the store quiesced/locked: the three
@@ -293,7 +263,7 @@ class StreamService:
                     "read_only": bool(getattr(self.store, "read_only",
                                               False)),
                     "wal_seq": int(getattr(self.store, "wal_seq", 0))}
-        return await self._guarded(read)
+        return await self._exclusive(read)
 
     async def _with_deadline(self, coro):
         """Apply the per-request deadline.  The operation is shielded and
@@ -396,15 +366,14 @@ async def serve(store, host: str = "127.0.0.1", port: int = 0,
                 limit: int = LINE_LIMIT, **service_kwargs):
     """Start the NDJSON endpoint; returns the ``asyncio.Server`` (its
     ``sockets[0].getsockname()`` carries the bound port when ``port=0``).
-    Extra keyword args configure :class:`StreamService` (``pipelined``,
-    ``max_workers``, ``inflight_budget``, ``backpressure``)."""
+    Extra keyword args configure :class:`StreamService` (``max_workers``,
+    ``inflight_budget``, ``backpressure``)."""
     service = StreamService(store, **service_kwargs)
     server = await asyncio.start_server(service.client, host, port,
                                         limit=limit)
     addr = server.sockets[0].getsockname()
     obs_trace.event("stream.serve", host=addr[0], port=addr[1],
                     G=store.sig.num_segments,
-                    pipelined=service.pipelined,
                     shards=getattr(store, "num_shards", 1))
     return server
 
@@ -419,8 +388,6 @@ def main(argv=None):
                     help="shard count (>1 builds a ShardedStreamStore)")
     ap.add_argument("--policy", default="round_robin",
                     choices=["round_robin", "key_hash"])
-    ap.add_argument("--serialized", action="store_true",
-                    help="disable the prepare/commit pipeline (PR-5 mode)")
     ap.add_argument("--wal", default=None, metavar="PATH",
                     help="write-ahead log file (durable ingest; an "
                          "existing log is recovered and resumed)")
@@ -455,8 +422,7 @@ def main(argv=None):
         if args.warmup:
             dt = store.warmup(args.warmup)
             print(f"warmup({args.warmup} rows): {dt:.3f}s")
-        server = await serve(store, args.host, args.port,
-                             pipelined=not args.serialized)
+        server = await serve(store, args.host, args.port)
         addr = server.sockets[0].getsockname()
         print(f"stream service on {addr[0]}:{addr[1]} "
               f"(G={args.groups}, aggs={args.aggs}, shards={args.shards}); "

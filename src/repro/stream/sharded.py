@@ -3,7 +3,7 @@ behind the single-store interface, merged exactly at query time.
 
 This is the scale-out shape of the stream engine (DESIGN.md §15.4): each
 shard owns a private :class:`~repro.ops.partial.PartialState` and
-coalescing buffer, so under the pipelined service each shard commits
+coalescing buffer, so under the service each shard commits
 behind its *own* lock and writer throughput stops serializing on one
 merge path.  The queryable state is ``merge_all`` over the shard states —
 and because the merge is commutative and associative over states with
@@ -23,9 +23,9 @@ Two assignment policies, both deterministic:
   future distributed tier needs (shard-local finalize, no cross-shard
   groups).
 
-Every shard shares the store's signature, so the compiled prepare
-pipeline (``pipeline_for`` is keyed on signature) — and its XLA
-executables — are shared too: adding shards adds no compile cost.
+Every shard shares the store's signature, so the compiled strategy
+programs of ``partial_agg`` (cached per signature and plan) are shared
+too: adding shards adds no compile cost.
 """
 from __future__ import annotations
 
@@ -41,7 +41,7 @@ from repro.obs import fingerprint as obs_fp
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.ops.partial import (AggSignature, PartialState, finalize,
-                               merge_all, merge_all_jit)
+                               merge_all_jit)
 from repro.stream.store import (StreamStore, _DurableMixin, _delivery_meta,
                                 _restore_best_snapshot, _state_tree,
                                 _tree_state)
@@ -64,7 +64,7 @@ class ShardedStreamStore(_DurableMixin):
 
     Args:
       num_segments / aggs / spec / method / levels / check_finite /
-        coalesce / compiled: as in :class:`StreamStore`; applied to every
+        coalesce: as in :class:`StreamStore`; applied to every
         shard (all shards share one :class:`AggSignature`).
       num_shards: shard count.  Throughput/layout knob only — the merged
         state is bit-identical for any value (pinned by tests).
@@ -80,9 +80,8 @@ class ShardedStreamStore(_DurableMixin):
     def __init__(self, num_segments: int, aggs=("sum",),
                  spec: Optional[ReproSpec] = None, method: str = "auto",
                  levels="auto", check_finite: bool = False,
-                 coalesce="auto", compiled: bool = True,
-                 num_shards: int = 2, policy: str = "round_robin",
-                 wal=None):
+                 coalesce="auto", num_shards: int = 2,
+                 policy: str = "round_robin", wal=None):
         if num_shards < 1:
             raise ValueError(f"num_shards must be >= 1, got {num_shards}")
         if policy not in _POLICIES:
@@ -92,10 +91,9 @@ class ShardedStreamStore(_DurableMixin):
         self._shards = [
             StreamStore(num_segments, aggs=aggs, spec=spec, method=method,
                         levels=levels, check_finite=check_finite,
-                        coalesce=coalesce, compiled=compiled)
+                        coalesce=coalesce)
             for _ in range(self.num_shards)]
         self.sig = self._shards[0].sig
-        self.compiled = self._shards[0].compiled
         # itertools.count is GIL-atomic, so round-robin assignment needs no
         # lock even when many service workers prepare concurrently.
         self._rr = itertools.count()
@@ -119,7 +117,7 @@ class ShardedStreamStore(_DurableMixin):
             out.append((int(idx), v[mask], k.reshape(-1)[mask]))
         return out
 
-    # -- uniform shard interface (what the pipelined service drives) ------
+    # -- uniform shard interface (what the service drives) ---------------
 
     def _prepare_parts(self, values, keys):
         """``[(shard_index, prepared_state_or_None, rows)]`` — pure (the
@@ -180,8 +178,7 @@ class ShardedStreamStore(_DurableMixin):
         if len(states) == 1:
             return states[0]
         with obs_trace.span("stream.shard_merge", shards=len(states)):
-            return (merge_all_jit(states) if self.compiled
-                    else merge_all(states))
+            return merge_all_jit(states)
 
     def query(self) -> dict:
         with obs_trace.span("stream.query", shards=self.num_shards):
@@ -220,7 +217,8 @@ class ShardedStreamStore(_DurableMixin):
 
     def warmup(self, batch_rows: int) -> float:
         """Pre-trace the ingest path (shared across shards — one shard's
-        warmup compiles for all, since the pipeline is signature-keyed)."""
+        warmup compiles for all, since the compiled programs are keyed on
+        the signature)."""
         return self._shards[0].warmup(batch_rows)
 
     # -- snapshot / restore ------------------------------------------------
@@ -254,8 +252,7 @@ class ShardedStreamStore(_DurableMixin):
     def restore(cls, directory: str, step: Optional[int] = None,
                 method: str = "auto", levels="auto",
                 check_finite: bool = False, coalesce="auto",
-                compiled: bool = True, num_shards: int = 2,
-                policy: str = "round_robin",
+                num_shards: int = 2, policy: str = "round_robin",
                 verify: bool = True) -> "ShardedStreamStore":
         """Rebuild from any stream-store snapshot: the merged state lands in
         shard 0 (one more legal partition of the row multiset), subsequent
@@ -268,8 +265,8 @@ class ShardedStreamStore(_DurableMixin):
         sig = AggSignature.from_json(extra["sig"])
         store = cls(sig.num_segments, aggs=sig.aggs, spec=sig.spec,
                     method=method, levels=levels, check_finite=check_finite,
-                    coalesce=coalesce, compiled=compiled,
-                    num_shards=num_shards, policy=policy)
+                    coalesce=coalesce, num_shards=num_shards,
+                    policy=policy)
         shard0 = store._shards[0]
         skeleton = _state_tree(shard0._state)
         tree, _ = ckpt.restore(directory, skeleton, step=manifest["step"])
@@ -286,7 +283,7 @@ class ShardedStreamStore(_DurableMixin):
     def recover(cls, wal, snapshot_dir: Optional[str] = None,
                 method: str = "auto", levels="auto",
                 check_finite: bool = False, coalesce="auto",
-                compiled: bool = True, num_shards: int = 2,
+                num_shards: int = 2,
                 policy: str = "round_robin") -> "ShardedStreamStore":
         """Rebuild from (newest verifiable snapshot + WAL replay), exactly
         as :meth:`StreamStore.recover` — the shard count and policy may
@@ -303,14 +300,12 @@ class ShardedStreamStore(_DurableMixin):
                     cls, snapshot_dir, wal.sig,
                     dict(method=method, levels=levels,
                          check_finite=check_finite, coalesce=coalesce,
-                         compiled=compiled, num_shards=num_shards,
-                         policy=policy))
+                         num_shards=num_shards, policy=policy))
             if store is None:
                 store = cls(wal.sig.num_segments, aggs=wal.sig.aggs,
                             spec=wal.sig.spec, method=method, levels=levels,
                             check_finite=check_finite, coalesce=coalesce,
-                            compiled=compiled, num_shards=num_shards,
-                            policy=policy)
+                            num_shards=num_shards, policy=policy)
             store._replay(wal, from_seq=store.wal_seq)
             store._attach_wal(wal)
         obs_metrics.counter("stream_recoveries_total").inc()

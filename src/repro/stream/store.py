@@ -40,8 +40,8 @@ from repro.obs import fingerprint as obs_fp
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.ops.partial import (AggSignature, PartialState, empty_partial,
-                               finalize, merge_all, merge_all_jit,
-                               partial_agg, pipeline_for, state_nbytes)
+                               finalize, merge_all_jit, partial_agg,
+                               state_nbytes)
 from repro.ops.plan import PartialPlan, plan_partial
 from repro.runtime import faultinject
 from repro.stream.wal import (DedupIndex, WalUnavailable, WriteAheadLog,
@@ -194,14 +194,6 @@ class StreamStore(_DurableMixin):
         (default) lets :func:`plan_partial` pick from the first batch's
         size; an int pins it.  Throughput knob only — any value yields
         bit-identical query results.
-      compiled: route ``prepare`` through the shared
-        :class:`~repro.ops.partial.PartialPipeline` (cached XLA
-        executables per plan decision) and ``flush`` through the jitted
-        ``merge_all``.  Default on — eager ``partial_agg`` re-traces per
-        call, which dominated measured ingest cost ~10:1.  ``False``
-        restores the fully eager PR-5 paths (one-shot stores, or as the
-        measured baseline in ``bench_stream.py``); either setting yields
-        bit-identical states (pinned by tests and the bench gate).
       wal: a :class:`~repro.stream.wal.WriteAheadLog` (or a path to
         create/open one) that every ingested delta is appended to *before*
         it is applied.  With a WAL, ``recover(wal, snapshot_dir)`` rebuilds
@@ -215,14 +207,11 @@ class StreamStore(_DurableMixin):
     def __init__(self, num_segments: int, aggs=("sum",),
                  spec: Optional[ReproSpec] = None, method: str = "auto",
                  levels="auto", check_finite: bool = False,
-                 coalesce="auto", compiled: bool = True, wal=None):
+                 coalesce="auto", wal=None):
         self.sig = AggSignature.build(aggs, num_segments, spec)
         self.method = method
         self.levels = tuple(levels) if isinstance(levels, list) else levels
         self.check_finite = check_finite
-        self.compiled = bool(compiled)
-        self._pipeline = pipeline_for(
-            self.sig, method, self.levels, check_finite) if compiled else None
         self._coalesce = coalesce
         self._state = empty_partial(num_segments, self.sig.aggs,
                                     self.sig.spec)
@@ -264,13 +253,10 @@ class StreamStore(_DurableMixin):
             return None
         t0 = time.perf_counter()
         with obs_trace.span("stream.prepare", rows=n):
-            if self._pipeline is not None:
-                st = self._pipeline(values, keys)
-            else:
-                st = partial_agg(values, keys, self.sig.num_segments,
-                                 aggs=self.sig.aggs, spec=self.sig.spec,
-                                 method=self.method, levels=self.levels,
-                                 check_finite=self.check_finite)
+            st = partial_agg(values, keys, self.sig.num_segments,
+                             aggs=self.sig.aggs, spec=self.sig.spec,
+                             method=self.method, levels=self.levels,
+                             check_finite=self.check_finite)
         obs_metrics.histogram("stream_prepare_seconds").observe(
             time.perf_counter() - t0)
         return st
@@ -340,8 +326,8 @@ class StreamStore(_DurableMixin):
                         "merged": self.merged_batches}
             return self.commit(st, n)
 
-    # Uniform shard interface (the pipelined service drives stores through
-    # these, so a plain store is the one-shard case of ShardedStreamStore).
+    # Uniform shard interface (the service drives stores through these,
+    # so a plain store is the one-shard case of ShardedStreamStore).
 
     num_shards = 1
 
@@ -363,8 +349,7 @@ class StreamStore(_DurableMixin):
         t0 = time.perf_counter()
         with obs_trace.span("stream.merge", pending=len(self._pending)):
             states = [self._state] + self._pending
-            self._state = (merge_all_jit(states) if self.compiled
-                           else merge_all(states))
+            self._state = merge_all_jit(states)
         self.merged_batches += len(self._pending)
         self._pending = []
         obs_metrics.histogram("stream_merge_seconds").observe(
@@ -409,9 +394,7 @@ class StreamStore(_DurableMixin):
             scratch = empty_partial(self.sig.num_segments, self.sig.aggs,
                                     self.sig.spec)
             states = [scratch] + [st] * depth
-            merged = (merge_all_jit(states) if self.compiled
-                      else merge_all(states))
-            finalize(merged)
+            finalize(merge_all_jit(states))
         dt = time.perf_counter() - t0
         obs_trace.event("stream.warmup", rows=n, seconds=dt)
         obs_metrics.gauge("stream_warmup_seconds").set(dt)
@@ -479,7 +462,7 @@ class StreamStore(_DurableMixin):
     def restore(cls, directory: str, step: Optional[int] = None,
                 method: str = "auto", levels="auto",
                 check_finite: bool = False, coalesce="auto",
-                compiled: bool = True, verify: bool = True) -> "StreamStore":
+                verify: bool = True) -> "StreamStore":
         """Rebuild a store from a snapshot, bit-exactly.
 
         The signature comes from the manifest (no caller-side schema to get
@@ -497,7 +480,7 @@ class StreamStore(_DurableMixin):
         sig = AggSignature.from_json(extra["sig"])
         store = cls(sig.num_segments, aggs=sig.aggs, spec=sig.spec,
                     method=method, levels=levels, check_finite=check_finite,
-                    coalesce=coalesce, compiled=compiled)
+                    coalesce=coalesce)
         skeleton = _state_tree(store._state)
         tree, _ = ckpt.restore(directory, skeleton, step=manifest["step"])
         if verify:
@@ -512,8 +495,8 @@ class StreamStore(_DurableMixin):
     @classmethod
     def recover(cls, wal, snapshot_dir: Optional[str] = None,
                 method: str = "auto", levels="auto",
-                check_finite: bool = False, coalesce="auto",
-                compiled: bool = True) -> "StreamStore":
+                check_finite: bool = False, coalesce="auto"
+                ) -> "StreamStore":
         """Rebuild a crashed store from durable state only: the newest
         *verifiable* snapshot (value-fingerprint checked; corrupt or torn
         snapshots are skipped, falling back to older ones or to an empty
@@ -533,13 +516,11 @@ class StreamStore(_DurableMixin):
                 store = _restore_best_snapshot(
                     cls, snapshot_dir, wal.sig,
                     dict(method=method, levels=levels,
-                         check_finite=check_finite, coalesce=coalesce,
-                         compiled=compiled))
+                         check_finite=check_finite, coalesce=coalesce))
             if store is None:
                 store = cls(wal.sig.num_segments, aggs=wal.sig.aggs,
                             spec=wal.sig.spec, method=method, levels=levels,
-                            check_finite=check_finite, coalesce=coalesce,
-                            compiled=compiled)
+                            check_finite=check_finite, coalesce=coalesce)
             store._replay(wal, from_seq=store.wal_seq)
             store._attach_wal(wal)
         obs_metrics.counter("stream_recoveries_total").inc()

@@ -8,6 +8,7 @@ store over the same rows.
 """
 import asyncio
 
+import jax
 import numpy as np
 import pytest
 
@@ -81,8 +82,7 @@ def test_pipelined_concurrent_writers_match_one_shot(dataset, seed):
     v, k, want = dataset
 
     async def run():
-        service = StreamService(StreamStore(G, aggs=AGGS), pipelined=True,
-                                max_workers=4)
+        service = StreamService(StreamStore(G, aggs=AGGS), max_workers=4)
         await _drive(service, _random_batches(v, k, seed, writers=5), seed)
         fps = await service.fingerprints()
         stats = await service.stats()
@@ -102,7 +102,7 @@ def test_sharded_pipelined_service_matches_one_shot(dataset, shards, policy):
     async def run():
         store = ShardedStreamStore(G, aggs=AGGS, num_shards=shards,
                                    policy=policy)
-        service = StreamService(store, pipelined=True, max_workers=4)
+        service = StreamService(store, max_workers=4)
         await _drive(service, _random_batches(v, k, 3, writers=4), 3)
         fps = await service.fingerprints()
         stats = await service.stats()
@@ -137,8 +137,7 @@ def test_snapshot_mid_ingest_drains_and_restores_bit_exactly(dataset,
     n, step = v.shape[0], 75
 
     async def run():
-        service = StreamService(StreamStore(G, aggs=AGGS), pipelined=True,
-                                max_workers=4)
+        service = StreamService(StreamStore(G, aggs=AGGS), max_workers=4)
 
         async def writer(lo):
             for a in range(lo, lo + n // 4, step):
@@ -178,7 +177,7 @@ def test_backpressure_reject_loses_nothing(dataset):
     v, k, _ = dataset
 
     async def run():
-        service = StreamService(StreamStore(G, aggs=AGGS), pipelined=True,
+        service = StreamService(StreamStore(G, aggs=AGGS),
                                 inflight_budget=1024, backpressure="reject")
         # simulate a concurrent in-flight batch holding the whole budget
         await service._admit(1024)
@@ -201,7 +200,7 @@ def test_backpressure_wait_blocks_then_completes(dataset):
     v, k, _ = dataset
 
     async def run():
-        service = StreamService(StreamStore(G, aggs=AGGS), pipelined=True,
+        service = StreamService(StreamStore(G, aggs=AGGS),
                                 inflight_budget=1024, backpressure="wait")
         await service._admit(1024)
         task = asyncio.ensure_future(service.ingest(v[:200], k[:200]))
@@ -223,7 +222,7 @@ def test_oversized_batch_admitted_when_queue_empty(dataset):
 
     async def run():
         # a single batch larger than the whole budget must still run
-        service = StreamService(StreamStore(G, aggs=AGGS), pipelined=True,
+        service = StreamService(StreamStore(G, aggs=AGGS),
                                 inflight_budget=8, backpressure="reject")
         out = await service.ingest(v[:500], k[:500])
         service.close()
@@ -241,8 +240,7 @@ def test_stats_consistent_under_concurrent_ingest(dataset):
     step = 75  # divides each writer's span: partition, no overlap
 
     async def run():
-        service = StreamService(StreamStore(G, aggs=AGGS), pipelined=True,
-                                max_workers=4)
+        service = StreamService(StreamStore(G, aggs=AGGS), max_workers=4)
 
         async def writer(lo, hi):
             for a in range(lo, hi, step):
@@ -284,12 +282,14 @@ def test_prepare_commit_composes_to_ingest(dataset):
 
 def test_compiled_store_bitwise_equals_eager(dataset):
     v, k, want = dataset
-    eager = StreamStore(G, aggs=AGGS, compiled=False)
-    comp = StreamStore(G, aggs=AGGS, compiled=True)
+    eager, comp = StreamStore(G, aggs=AGGS), StreamStore(G, aggs=AGGS)
     for bv, bk in _random_batches(v, k, 13, writers=1)[0]:
-        eager.ingest(bv, bk)
+        with jax.disable_jit():
+            eager.ingest(bv, bk)
         comp.ingest(bv, bk)
-    assert eager.fingerprints() == comp.fingerprints() == want
+    with jax.disable_jit():
+        fps = eager.fingerprints()
+    assert fps == comp.fingerprints() == want
 
 
 def test_merge_all_jit_bitwise_equals_eager(dataset):
