@@ -306,57 +306,89 @@ def _check_finite(v, X, cols):
 # stage 1: partial aggregation
 # ---------------------------------------------------------------------------
 
-def _resolve_levels(levels, X, e1, spec: ReproSpec):
-    """Turn the ``levels`` request into (static window | None, chunk_skip).
+def _window(X, spec: ReproSpec):
+    """The prescan's device side, one program: the per-column
+    ``required_e1`` and the int32 vector ``(min lo, max hi, chunk_skip)``
+    over the chunked rows' live level windows.
 
-    ``"auto"`` + concrete inputs = the prescan pass: one vectorized stream
-    over the rows yields per-chunk, per-column exponent stats; the union of
-    the live windows becomes the static window, and per-chunk top-skipping
-    is enabled only when some chunk can prune *more* than the union (i.e.
-    the data is magnitude-heterogeneous) — homogeneous inputs skip the
-    per-chunk switch entirely so the hot loop stays branchless.
+    One vectorized stream over the rows yields per-chunk, per-column
+    exponent stats; the union of their windows is ``[min lo, max hi)``.
+    The flag is set when some chunk's own window starts above the union's
+    ``lo``, i.e. the data is magnitude-heterogeneous and that chunk can
+    skip more top levels than the static window.  Integer and exponent
+    arithmetic only, so compiling it moves no value against its eager run
+    under ``jax.disable_jit()``.
     """
-    if levels is None:
-        return None, False
-    if levels != "auto":
-        return prescan.check_levels(levels, spec), False
-    if not (prescan.is_concrete(X) and prescan.is_concrete(e1)):
-        return None, False                      # traced: full window
-    if X.shape[0] == 0:
-        return (0, 1), False                    # empty input: all-zero table
+    e1 = acc_mod.required_e1(X, spec, axis=0)                # per-column
     probe = aggregates.default_chunk("scatter", spec)
     stats = prescan.chunk_stats(
         aggregates.pad_and_chunk(X, probe), spec)            # (nblk, ncols)
     lo_a, hi_a = prescan.level_window(stats, e1[None, :], spec)
-    lo = _host_sync("lo", int, jnp.min(lo_a))
-    hi = _host_sync("hi", int, jnp.max(hi_a))
-    if lo >= hi:
-        lo, hi = 0, 1                            # degenerate: all-zero input
-    # heterogeneous when some chunk's own window starts above the union's
-    # lo, i.e. that chunk can skip more top levels than the static window
-    chunk_skip = hi - lo > 1 and _host_sync(
-        "chunk_skip", bool,
-        jnp.max(jnp.min(lo_a.reshape(lo_a.shape[0], -1), axis=1)) > lo)
-    return (lo, hi), chunk_skip
+    lo = jnp.min(lo_a)
+    skip = jnp.max(jnp.min(lo_a, axis=1)) > lo
+    return e1, jnp.stack([lo, jnp.max(hi_a), skip.astype(jnp.int32)])
 
 
-def _host_sync(what: str, convert, x):
-    """``convert(x)`` of a dispatched device array: the host waits for the
+@functools.lru_cache(maxsize=64)
+def _window_program(spec: ReproSpec):
+    """:func:`_window` for one format, compiled; jit re-specializes per
+    (rows, ncols)."""
+    return jax.jit(functools.partial(_window, spec=spec))
+
+
+def _resolve_levels(levels, X, spec: ReproSpec):
+    """Turn the ``levels`` request into ``(e1, static window | None,
+    chunk_skip, path)``.
+
+    ``"auto"`` + concrete, non-empty inputs = the prescan pass: one
+    compiled program (:func:`_window`) and one blocking read of its
+    ``(lo, hi, chunk_skip)`` vector; per-chunk top-skipping is enabled only
+    when some chunk can prune *more* than the union (homogeneous inputs
+    skip the per-chunk switch entirely so the hot loop stays branchless).
+    ``path`` says how the window was found: ``compiled``, ``eager`` under
+    ``jax.disable_jit``, or ``skipped`` where no prescan ran (a window
+    given or none wanted, traced inputs, no rows).
+    """
+    if levels is None:
+        window = None
+    elif levels != "auto":
+        window = prescan.check_levels(levels, spec)
+    elif not prescan.is_concrete(X):
+        window = None                           # traced: full window
+    elif X.shape[0] == 0:
+        window = (0, 1)                         # empty input: all-zero table
+    else:
+        path = "eager" if jax.config.jax_disable_jit else "compiled"
+        e1, vec = _window_program(spec)(X)
+        lo, hi, skip = _host_sync("window", vec).tolist()
+        if lo >= hi:
+            lo, hi = 0, 1                       # degenerate: all-zero input
+        return e1, (lo, hi), hi - lo > 1 and bool(skip), path
+    if X.shape[0]:
+        e1 = acc_mod.required_e1(X, spec, axis=0)
+    else:       # no rows: the lattice's bottom, as all-zero rows give it
+        e1 = jnp.full(X.shape[1:], spec.lattice_lo, jnp.int32)
+    return e1, window, False, "skipped"
+
+
+def _host_sync(what: str, x) -> np.ndarray:
+    """The host copy of a dispatched device array: the host waits for the
     device, timed as a ``groupby.host_sync`` span."""
     with obs_trace.span("groupby.host_sync", what=what):
-        return convert(x)
+        return np.asarray(x)
 
 
 def _prescan(X, levels, spec: ReproSpec):
     """Per-column ``required_e1`` and the resolved level window, timed as
-    the ``groupby.prescan`` span: (e1, levels, chunk_skip)."""
+    the ``groupby.prescan`` span and counted as
+    ``repro_groupby_prescan_total{path}``: (e1, levels, chunk_skip)."""
     with obs_trace.span("groupby.prescan", n=int(X.shape[0]),
                         ncols=X.shape[1]) as sp:
-        e1 = acc_mod.required_e1(X, spec, axis=0)            # per-column
-        lv, chunk_skip = _resolve_levels(levels, X, e1, spec)
+        e1, lv, chunk_skip, path = _resolve_levels(levels, X, spec)
+        obs_metrics.counter("repro_groupby_prescan_total", path=path).inc()
         sp.set(levels=list(lv) if lv is not None else None,
                chunk_skip=bool(chunk_skip), L=spec.L,
-               L_eff=prescan.window_length(lv, spec))
+               L_eff=prescan.window_length(lv, spec), path=path)
     return e1, lv, chunk_skip
 
 
@@ -455,8 +487,9 @@ def partial_agg(values, keys, num_segments: int, aggs=("sum",),
     any micro-batching of the rows merges to the one-shot state bit for
     bit.
 
-    The front runs eagerly, because its outputs are the compiled tail's
-    static keys: the column build, the prescan (its host syncs), the plan.
+    The front runs on the host, because its outputs are the compiled
+    tail's static keys: the eager column build, the prescan (one compiled
+    program and one host read), the plan.
     The strategy tail is one cached program per (signature, plan, level
     window, chunk_skip) decision (:func:`_tail`), so a repeated batch shape
     compiles nothing.

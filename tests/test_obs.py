@@ -279,8 +279,7 @@ def test_groupby_records_share_one_root_id():
     assert {r["root_id"] for r in records} == {root["span_id"]}
 
 
-@pytest.mark.parametrize("levels, syncs", [("auto", ["lo", "hi",
-                                                     "chunk_skip"]),
+@pytest.mark.parametrize("levels, syncs", [("auto", ["window"]),
                                            (None, [])])
 def test_host_syncs_of_the_prescan(levels, syncs):
     records = _query_records(levels=levels)
